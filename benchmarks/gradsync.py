@@ -14,7 +14,6 @@ import numpy as np
 
 from benchmarks.common import (Row, block, derived_collective_time,
                                percentile_rows, timeit_samples)
-from repro import compat
 from repro.core.backends import available_modes, get_backend
 from repro.configs.base import CommConfig, RunConfig, ShapeConfig
 from repro.configs.registry import get_config
@@ -46,7 +45,7 @@ def run(mesh=None, *, arch: str = "qwen1.5-4b-reduced",
         __import__("repro.models.api", fromlist=["specs"]).specs(cfg)))
 
     rows = []
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for mode in modes:
             run_cfg = RunConfig(
                 model=cfg, shape=shape,

@@ -22,7 +22,6 @@ from jax.sharding import PartitionSpec as P
 from benchmarks.common import (Row, block, derived_collective_time,
                                percentile_rows, slice_view, timeit,
                                timeit_samples)
-from repro import compat
 from repro.configs.base import CommConfig
 from repro.core.backends import pipeline
 from repro.core.backends.base import SyncContext
@@ -46,7 +45,7 @@ def _pingpong_fn(mesh, n_channels: int, msg_elems: int, n_dev: int):
             outs.append(z)
         return tuple(outs)
 
-    f = compat.shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
                       in_specs=tuple([P("data", None)] * n_channels),
                       out_specs=tuple([P("data", None)] * n_channels),
                       check_vma=False)
@@ -132,8 +131,8 @@ def _slice_exchange_fn(mesh, comm: CommConfig, payload_elems: int):
         red, _ = pipeline.reduce_slices(sl, ctx)
         return red.reshape(-1)[:payload_elems]
 
-    f = compat.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                         check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                      check_vma=False)
     return jax.jit(f)
 
 

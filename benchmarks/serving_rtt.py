@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import (Row, block, percentile_rows, timeit_samples)
-from repro import compat
 from repro.configs.base import CommConfig
 from repro.core.backends import pipeline
 from repro.launch import hlo_analysis as hlo
@@ -68,11 +67,11 @@ def _rtt_fn(mesh, n_conns: int, n_dev: int, direction: str):
                 outs.append(trip(x, perm_bwd, perm_fwd))
         return tuple(outs)
 
-    f = compat.shard_map(body, mesh=mesh,
-                         in_specs=tuple([P("data", None)] * n_conns),
-                         out_specs=tuple([P("data", None)] * n_conns
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=tuple([P("data", None)] * n_conns),
+                      out_specs=tuple([P("data", None)] * n_conns
                                          * (2 if direction == "bi" else 1)),
-                         check_vma=False)
+                      check_vma=False)
     return jax.jit(f)
 
 
@@ -144,8 +143,8 @@ def _topo_emit_fn(mesh, ctx, elems: int):
     def body(x):
         return pipeline.emit_flat(x.reshape(-1), ctx, "all_reduce")
 
-    f = compat.shard_map(body, mesh=mesh, in_specs=P(axes),
-                         out_specs=P(), check_vma=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P(axes),
+                      out_specs=P(), check_vma=False)
     return jax.jit(f)
 
 

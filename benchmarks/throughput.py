@@ -25,7 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import (Row, block, derived_collective_time,
                                slice_view, timeit)
-from repro import compat
 from repro.configs.base import CommConfig
 from repro.core.backends import pipeline
 from repro.core.backends.base import SyncContext
@@ -74,7 +73,7 @@ def _stream_fn(mesh, mode: str, n_channels: int, n_msgs: int,
                 outs.append(red.reshape(-1)[: x.size].reshape(x.shape))
         return tuple(outs)
 
-    f = compat.shard_map(body, mesh=mesh,
+    f = jax.shard_map(body, mesh=mesh,
                       in_specs=tuple([P()] * n_channels),
                       out_specs=tuple([P()] * n_channels),
                       check_vma=False)

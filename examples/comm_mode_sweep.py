@@ -8,7 +8,6 @@ the performance claim (hadroNIO's aggregation = fewer, larger sends).
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs.base import CommConfig, RunConfig, ShapeConfig
 from repro.core.backends import available_modes, get_backend
 from repro.configs.registry import get_config
@@ -40,7 +39,7 @@ def main():
                                         hierarchical=False),
                         lr=1e-3, total_steps=8, warmup_steps=2)
         # collective schedule from the compiled step
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_fn, state_sh, batch_sh_fn = steps_mod.make_train_step(
                 run, mesh)
             state = jax.device_put(

@@ -200,8 +200,7 @@ class CommConfig:
 
     ``pack`` selects the pack/cast/error-feedback copy-path implementation
     (the paper's gathering-write hot spot): ``jnp`` (reference) or
-    ``pallas`` (fused one-pass kernel, kernels/ring_pack.py; falls back to
-    jnp via repro.compat when pallas is unavailable). The same switch
+    ``pallas`` (fused one-pass kernel, kernels/ring_pack.py). The same switch
     selects the unpack-stage implementation (the scattering-read epilogue
     — one fused cast-from-wire-dtype pass over the collective results).
 
@@ -286,8 +285,7 @@ class CommConfig:
         if self.pack not in self.PACK_IMPLS:
             raise ValueError(
                 f"unknown comm.pack {self.pack!r}: expected one of "
-                f"{self.PACK_IMPLS} (pallas falls back to jnp when the "
-                "kernel toolchain is unavailable)")
+                f"{self.PACK_IMPLS}")
         if self.aggregate not in self.AGGREGATES:
             raise ValueError(
                 f"unknown comm.aggregate {self.aggregate!r}: expected one "

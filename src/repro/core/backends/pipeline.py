@@ -16,9 +16,7 @@ write); this module owns the wire stages:
   spot). ``comm.pack`` selects the implementation: ``"pallas"`` runs the
   fused one-HBM-pass kernel (kernels/ring_pack.py, interpret mode
   off-TPU), ``"jnp"`` the reference elementwise path; both produce
-  bit-identical wire bytes. Selection falls back through
-  :func:`repro.compat.pallas_available` so pallas-less environments run
-  every backend unchanged. int8 needs a per-slice amax reduction the
+  bit-identical wire bytes. int8 needs a per-slice amax reduction the
   kernel does not fuse, so it always takes the jnp path.
 * :func:`begin_emission` / :func:`stage_slices` / :func:`flush_ready` /
   :func:`finish_emission` — the worker-per-connection schedule as a
@@ -81,7 +79,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import CommConfig
 from repro.core import compress as comp
 from repro.core.channels import ChannelFill, CommChannel, make_channels
@@ -268,14 +265,6 @@ def channels_for(ctx: SyncContext, n_slices: int) -> list[CommChannel]:
                          leaders=leaders)
 
 
-def pack_impl(comm: CommConfig) -> str:
-    """Resolve the pack/unpack-stage implementation: honor ``comm.pack``
-    when the pallas toolchain is importable, else fall back to jnp."""
-    if comm.pack == "pallas" and compat.pallas_available():
-        return "pallas"
-    return "jnp"
-
-
 def pack_wire(slices: jax.Array, ef, comm: CommConfig):
     """The pack stage over a ``(n, S)`` slice view: one fused pass doing
     add-EF, cast-to-wire-dtype, and residual capture.
@@ -289,7 +278,7 @@ def pack_wire(slices: jax.Array, ef, comm: CommConfig):
         return q, new_ef, scale
     with_ef = comm.compress == "bf16"
     wire_dtype = "bfloat16" if with_ef else jnp.dtype(slices.dtype).name
-    if pack_impl(comm) == "pallas":
+    if comm.pack == "pallas":
         from repro.kernels import ops
         n, s = slices.shape
         wire, new_ef = ops.pack_slices(slices.reshape(-1), ef, n_slices=n,
@@ -312,7 +301,7 @@ def unpack_wire(wire: jax.Array, comm: CommConfig,
     already in ``out_dtype`` needs no pass at all."""
     if wire.dtype == jnp.dtype(out_dtype):
         return wire
-    if pack_impl(comm) == "pallas":
+    if comm.pack == "pallas":
         from repro.kernels import ops
         return ops.unpack_slices(
             wire, out_dtype=jnp.dtype(out_dtype).name).reshape(wire.shape)

@@ -19,7 +19,10 @@ def bf16_compress(slices: jax.Array, ef: jax.Array | None):
     if ef is not None:
         slices = slices + ef
     wire = slices.astype(jnp.bfloat16)
-    new_ef = slices - wire.astype(jnp.float32)
+    # XLA:TPU folds an f32->bf16->f32 convert pair inside a fusion, which
+    # would zero the residual; reduce_precision rounds exactly as the cast
+    new_ef = slices - jax.lax.reduce_precision(slices, exponent_bits=8,
+                                               mantissa_bits=7)
     return wire, new_ef
 
 

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -125,7 +124,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_CHUNK = 128
 DEFAULT_WBLOCK = 512
@@ -85,7 +84,7 @@ def rglru_kernel(a: jax.Array, b: jax.Array, h0: jax.Array, *,
             jax.ShapeDtypeStruct((bsz, w), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((wblock,), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

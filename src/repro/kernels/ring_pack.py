@@ -10,12 +10,15 @@ into ONE HBM read + one write per element, tiled through VMEM.
     wire[i]   = cast(flat[i] + ef[i], wire_dtype)
     new_ef[i] = (flat[i] + ef[i]) - f32(wire[i])
 
-Block layout: the flat buffer is viewed as (n_slices, slice_elems); grid =
-(n_slices, slice_elems // LANE_BLOCK); each program moves one (1, 8·128·k)
-tile HBM->VMEM->HBM. slice_elems is 512-aligned by the plan (aggregation
-.make_plan; ring_buffer.plan_slices additionally rounds capacity-grown
-slices to 512 BYTES — at least 128 f32 lanes — for direct byte-level
-consumers), so tiles are always lane-aligned.
+Block layout: every pass is elementwise, so the ``(n_slices,
+slice_elems)`` buffer is viewed as ``(rows, LANES)`` — 128-lane rows in
+memory order — and tiled by row blocks: the whole row count when it fits
+one tile (a block equal to the array always lowers), otherwise
+``MAX_BLOCK_ROWS``, a multiple of 16, which meets Mosaic's (8, 128) f32
+and (16, 128) bf16 tiling rule; a partial last tile is masked by the
+pipeline. Slices are 512-aligned by the plan
+(aggregation.make_plan; ring_buffer.plan_slices rounds to 512 BYTES — at
+least 128 f32 lanes), so every buffer is a whole number of rows.
 
 ``unpack_slices_kernel`` is the scattering-read counterpart — the live
 unpack stage of the wire pipeline (backends/pipeline.unpack_wire): one
@@ -24,24 +27,27 @@ results, replacing a per-slice ``.astype`` epilogue.
 """
 from __future__ import annotations
 
-import functools
-import math
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE_BLOCK = 8 * 128 * 4          # 4096 f32 = 16 KiB per tile per buffer
+LANES = 128
+# (1024, 128) f32 = 512 KiB per buffer; the EF pack moves four buffers,
+# double-buffered: 4 MiB of VMEM, inside the default scoped limit
+MAX_BLOCK_ROWS = 1024
 
 
-def _block_for(slice_elems: int, block: int) -> int:
-    """Largest tile <= ``block`` that divides ``slice_elems`` exactly.
-    Slices are 512-aligned by the plan, so the gcd never drops below the
-    lane granularity for any 512-aligned slice length."""
-    blk = min(block, slice_elems)
-    if slice_elems % blk:
-        blk = math.gcd(slice_elems, blk)
-    return blk
+def _rows(total: int) -> int:
+    if total % LANES:
+        raise ValueError(
+            f"ring_pack buffers must hold a multiple of {LANES} elements "
+            f"(got {total}); the slice plan keeps slices 512-byte aligned")
+    return total // LANES
+
+
+def _grid_spec(rows: int):
+    blk = min(rows, MAX_BLOCK_ROWS)
+    return pl.cdiv(rows, blk), pl.BlockSpec((blk, LANES), lambda i: (i, 0))
 
 
 def _pack_kernel(flat_ref, ef_ref, wire_ref, new_ef_ref):
@@ -60,52 +66,46 @@ def _unpack_kernel(wire_ref, out_ref):
 
 def pack_slices_kernel(flat: jax.Array, ef, n_slices: int,
                        slice_elems: int, wire_dtype,
-                       *, block: int = LANE_BLOCK, interpret: bool = False,
-                       with_ef: bool = True):
+                       *, interpret: bool = False, with_ef: bool = True):
     """flat: (n_slices * slice_elems,) f32. Returns (wire (n, S) of
     wire_dtype, new_ef (n, S) f32 or None)."""
     assert flat.shape == (n_slices * slice_elems,), flat.shape
-    blk = _block_for(slice_elems, block)
-    grid = (n_slices, slice_elems // blk)
-    x2 = flat.reshape(n_slices, slice_elems)
-    spec = pl.BlockSpec((1, blk), lambda i, j: (i, j))
+    rows = _rows(flat.shape[0])
+    grid, spec = _grid_spec(rows)
+    x2 = flat.reshape(rows, LANES)
+    wire_shape = jax.ShapeDtypeStruct((rows, LANES), jnp.dtype(wire_dtype))
+
+    def back(a):
+        return a.reshape(n_slices, slice_elems)
 
     if with_ef:
         if ef is None:
-            ef = jnp.zeros((n_slices, slice_elems), jnp.float32)
-        kernel = _pack_kernel
-        in_specs = [spec, spec]
-        args = (x2, ef)
-        out_shape = (jax.ShapeDtypeStruct((n_slices, slice_elems),
-                                          jnp.dtype(wire_dtype)),
-                     jax.ShapeDtypeStruct((n_slices, slice_elems),
-                                          jnp.float32))
-        out_specs = (spec, spec)
+            ef = jnp.zeros((rows, LANES), jnp.float32)
         wire, new_ef = pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape, interpret=interpret)(*args)
-        return wire, new_ef
+            _pack_kernel, grid=(grid,), in_specs=[spec, spec],
+            out_specs=(spec, spec),
+            out_shape=(wire_shape,
+                       jax.ShapeDtypeStruct((rows, LANES), jnp.float32)),
+            interpret=interpret, name="ring_pack_ef")(
+                x2, ef.reshape(rows, LANES))
+        return back(wire), back(new_ef)
 
     def kernel_no_ef(flat_ref, wire_ref):
         _pack_kernel(flat_ref, None, wire_ref, None)
 
     wire = pl.pallas_call(
-        kernel_no_ef, grid=grid, in_specs=[spec], out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((n_slices, slice_elems),
-                                       jnp.dtype(wire_dtype)),
-        interpret=interpret)(x2)
-    return wire, None
+        kernel_no_ef, grid=(grid,), in_specs=[spec], out_specs=spec,
+        out_shape=wire_shape, interpret=interpret, name="ring_pack")(x2)
+    return back(wire), None
 
 
 def unpack_slices_kernel(wire: jax.Array, out_dtype=jnp.float32,
-                         *, block: int = LANE_BLOCK,
-                         interpret: bool = False) -> jax.Array:
+                         *, interpret: bool = False) -> jax.Array:
     """(n, S) wire -> (n * S,) of out_dtype (one fused cast+copy pass)."""
-    n, s = wire.shape
-    blk = _block_for(s, block)
-    spec = pl.BlockSpec((1, blk), lambda i, j: (i, j))
+    rows = _rows(wire.size)
+    grid, spec = _grid_spec(rows)
     out = pl.pallas_call(
-        _unpack_kernel, grid=(n, s // blk), in_specs=[spec], out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((n, s), jnp.dtype(out_dtype)),
-        interpret=interpret)(wire)
-    return out.reshape(n * s)
+        _unpack_kernel, grid=(grid,), in_specs=[spec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.dtype(out_dtype)),
+        interpret=interpret, name="ring_unpack")(wire.reshape(rows, LANES))
+    return out.reshape(-1)

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 DEFAULT_CHUNK = 32
 
@@ -118,7 +117,7 @@ def wkv6_kernel(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((bh, hs, hs), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u, s0)

@@ -25,7 +25,6 @@ import traceback
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs.base import CommConfig, RunConfig
 from repro.core.backends import available_modes, get_backend
 from repro.configs.registry import SHAPES, ARCH_IDS, cell_skip_reason, \
@@ -138,7 +137,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = _lower_cell(cfg, shape, mesh, mode, microbatches)
         compiled = lowered.compile()
         t1 = time.time()

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs.base import RunConfig
 from repro.core.backends import get_backend
 from repro.checkpoint import CheckpointStore
@@ -183,7 +182,7 @@ def restore_elastic(store: CheckpointStore, run: RunConfig, mesh,
     if s is None:
         raise FileNotFoundError(f"no checkpoint under {store.dir}")
     n_shards = int(np.prod(list(mesh.shape.values())))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         _, state_sh, _ = steps_mod.make_train_step(run, mesh)
         if get_backend(run.comm.mode).manual:
             like = steps_mod.abstract_tac_state(run, n_shards,

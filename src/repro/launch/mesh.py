@@ -3,26 +3,24 @@
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — required for the dry-run's forced host device
 count to take effect first.
-
-``AxisType`` moved under ``jax.sharding`` in newer jax; the guarded import
-lives in :mod:`repro.compat` so a pinned older release still collects.
 """
 from __future__ import annotations
 
-from repro import compat
-from repro.compat import AxisType  # noqa: F401  (re-export, may be None)
+import jax
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
     """Arbitrary mesh for tests/benchmarks (host devices or real)."""
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serve_mesh(pods: int = 1, pod_axis: str = "pod", devices=None):
@@ -34,7 +32,6 @@ def make_serve_mesh(pods: int = 1, pod_axis: str = "pod", devices=None):
     ownership). ``devices`` defaults to every visible device; ``pods``
     must divide the count (the pod is a physical partition, not a
     round-robin)."""
-    import jax
     n = len(devices if devices is not None else jax.devices())
     if pods < 1:
         raise ValueError(f"pods must be >= 1, got {pods}")
@@ -45,13 +42,13 @@ def make_serve_mesh(pods: int = 1, pod_axis: str = "pod", devices=None):
             f"divides {n} (divisors: "
             f"{[d for d in range(1, n + 1) if n % d == 0]})")
     if pods == 1:
-        return compat.make_mesh((n,), ("data",))
-    return compat.make_mesh((pods, n // pods), (pod_axis, "data"))
+        return make_mesh((n,), ("data",))
+    return make_mesh((pods, n // pods), (pod_axis, "data"))
 
 
 def make_abstract_mesh(shape: tuple, axes: tuple):
-    """Device-free mesh for sharding-rule tests (signature-drift safe)."""
-    return compat.abstract_mesh(shape, axes)
+    """Device-free mesh for sharding-rule tests."""
+    return AbstractMesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:

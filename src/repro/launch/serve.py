@@ -43,6 +43,7 @@ from repro.configs.registry import get_config
 from repro.configs.base import CommConfig, ServeConfig, TenantConfig
 from repro.checkpoint import CheckpointStore
 from repro.core.backends import available_modes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving import (Request, RetryBudget, Supervisor,
                            SupervisorConfig, make_engine_group)
@@ -167,6 +168,7 @@ def main() -> int:
                         "registry JSON: poll/emission/loop/tenant/"
                         "supervisor counters) here")
     args = p.parse_args()
+    enable_compile_cache()
 
     if args.trace_out:
         obs.enable()

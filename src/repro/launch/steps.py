@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.core import backends as backends_mod
 from repro.core import tac
@@ -264,7 +263,7 @@ def make_train_step_tac(run: RunConfig, mesh):
         bspecs = batch_specs_fn(batch)
         # metrics take a replicated PREFIX spec: whatever dict the
         # backend's apply_update returns works without launcher edits
-        out = compat.shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(state_specs, bspecs),
             out_specs=(state_specs, replicated),

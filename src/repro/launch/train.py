@@ -39,7 +39,6 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from repro import compat
 from repro.core.backends import available_modes, get_backend
 
 from repro.configs.base import CommConfig, RunConfig, ShapeConfig
@@ -47,6 +46,7 @@ from repro.configs.registry import get_config
 from repro.checkpoint import CheckpointStore
 from repro.data import DataConfig, batch_at, make_source
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import api
 
@@ -110,7 +110,7 @@ class Trainer:
                 os._exit(42)
             self.watchdog = Watchdog(watchdog_secs, _abort)
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_fn, self.state_sh, batch_sh_fn = \
                 steps_mod.make_train_step(run, mesh)
             self._batch_sh_fn = batch_sh_fn
@@ -155,7 +155,7 @@ class Trainer:
         state, start = self.restore_or_init()
         metrics = {}
         losses = []
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             # double-buffered host data: build batch k+1 while step k runs
             next_batch = batch_at(self.source, self.dc, start)
             for step in range(start, run.total_steps):
@@ -245,8 +245,7 @@ def main() -> int:
     p.add_argument("--pack", default="jnp",
                    choices=list(CommConfig.PACK_IMPLS),
                    help="pack/cast/EF copy-path impl (pallas = fused "
-                        "ring_pack kernel; falls back to jnp off-TPU "
-                        "toolchains)")
+                        "ring_pack kernel)")
     p.add_argument("--aggregate", default="slice",
                    choices=list(CommConfig.AGGREGATES),
                    help="wire-flush granularity: 'slice' = one collective "
@@ -274,6 +273,7 @@ def main() -> int:
     p.add_argument("--watchdog-secs", type=float, default=0.0)
     p.add_argument("--max-restarts", type=int, default=None)
     args = p.parse_args()
+    enable_compile_cache()
 
     if args.mesh:
         dims = tuple(int(x) for x in args.mesh.split("x"))

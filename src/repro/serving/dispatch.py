@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import CommConfig, ModelConfig
 from repro.core.backends import get_backend
 from repro.core.backends.base import SyncContext
@@ -271,10 +270,10 @@ def _make_serve_step(cfg: ModelConfig, comm: CommConfig, mesh=None, *,
         return api.decode_step(params, cache, dec, cfg, no_shard,
                                logits_fn=head, expert_fn=expert_fn)
 
-    prefill = jax.jit(compat.shard_map(
+    prefill = jax.jit(jax.shard_map(
         prefill_body, mesh=mesh, in_specs=(P(), P()),
         out_specs=(P(), P()), check_vma=False))
-    decode = jax.jit(compat.shard_map(
+    decode = jax.jit(jax.shard_map(
         decode_body, mesh=mesh, in_specs=(P(), P(), P()),
         out_specs=(P(), P()), check_vma=False))
     step = ServeStep(prefill=prefill, decode=decode, n_shards=n_shards,

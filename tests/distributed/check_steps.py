@@ -6,7 +6,6 @@ from repro.configs.base import CommConfig, RunConfig
 from repro.configs.registry import get_config, get_shape
 from repro.launch import steps
 from repro.launch.mesh import make_mesh
-from repro import compat
 from repro.launch.sharding import batch_sharding
 from repro.models import api
 
@@ -24,7 +23,7 @@ mesh = make_mesh((4, 2), ("data", "model"))
 
 # --- GSPMD path ---
 run = RunConfig(model=cfg, shape=shape, comm=CommConfig(mode="gspmd"))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step_fn, state_sh, batch_sh_fn = steps.make_train_step(run, mesh)
     state = jax.device_put(steps.init_train_state(rng, run), state_sh)
     jitted = jax.jit(step_fn, in_shardings=(state_sh, batch_sh_fn(mesh, batch)),
@@ -42,7 +41,7 @@ for mode in ("sockets", "vma", "hadronio", "hadronio_overlap", "hadronio_rs"):
                     comm=CommConfig(mode=mode, slice_bytes=256 * 1024,
                                     ring_capacity_bytes=16 * 1024 * 1024,
                                     hierarchical=False))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, state_sh, batch_sh_fn = steps.make_train_step(run, mesh)
         state = jax.device_put(steps.init_tac_state(rng, run, 8), state_sh)
         jitted = jax.jit(step_fn, in_shardings=(state_sh, batch_sh_fn(mesh, batch)),
@@ -64,7 +63,7 @@ run = RunConfig(model=cfg, shape=shape, comm=CommConfig(mode="hadronio", hierarc
                 microbatches=2)
 batch16 = {"tokens": jax.random.randint(rng, (16, S), 0, cfg.vocab_size),
            "labels": jax.random.randint(rng, (16, S), 0, cfg.vocab_size)}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step_fn, state_sh, batch_sh_fn = steps.make_train_step(run, mesh)
     state = jax.device_put(steps.init_tac_state(rng, run, 8), state_sh)
     s1, m = jax.jit(step_fn, in_shardings=(state_sh, batch_sh_fn(mesh, batch16)),
@@ -74,7 +73,7 @@ with compat.set_mesh(mesh):
 # compression state threading
 run = RunConfig(model=cfg, shape=shape,
                 comm=CommConfig(mode="hadronio", compress="bf16", hierarchical=False))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step_fn, state_sh, batch_sh_fn = steps.make_train_step(run, mesh)
     state = jax.device_put(steps.init_tac_state(rng, run, 8), state_sh)
     s1, m = jax.jit(step_fn, in_shardings=(state_sh, batch_sh_fn(mesh, batch)),
@@ -92,7 +91,7 @@ for mode, hier in (("sockets", False), ("hadronio", True),
     run = RunConfig(model=cfg, shape=shape,
                     comm=CommConfig(mode=mode, slice_bytes=256 * 1024,
                                     hierarchical=hier))
-    with compat.set_mesh(mesh3):
+    with jax.set_mesh(mesh3):
         step_fn, state_sh, batch_sh_fn = steps.make_train_step(run, mesh3)
         state = jax.device_put(steps.init_tac_state(rng, run, 8, 2),
                                state_sh)

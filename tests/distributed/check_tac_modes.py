@@ -3,7 +3,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import CommConfig
 from repro.core import tac, aggregation as agg
 from repro.core.backends import get_backend

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from functools import partial
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import CommConfig, ServeConfig
 from repro.configs.registry import get_config
 from repro.core.hierarchical import (psum_hierarchical,

@@ -51,11 +51,11 @@ import os
 
 import numpy as np
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import CommConfig, RunConfig, ShapeConfig
 from repro.configs.registry import get_config
 from repro.core import tac
@@ -152,8 +152,8 @@ def test_sync_parity(mode, compress, pack):
         r = tac.sync_grads(g, comm, data_axis=("data",))
         return backend.gathered_grads(r, g)
 
-    out = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                                   out_specs=P()))(grads)
+    out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                out_specs=P(), check_vma=False))(grads)
     assert jax.tree.structure(out) == jax.tree.structure(grads)
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(grads)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -182,7 +182,7 @@ def _two_step(mode, compress):
     run = RunConfig(model=cfg, shape=ShapeConfig("t", "train", 16, 4),
                     comm=_comm(mode, compress, slice_bytes=16 * 1024))
     mesh = make_mesh((1,), ("data",))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, state_sh, _ = steps_mod.make_train_step(run, mesh)
         if get_backend(mode).manual:
             sds = steps_mod.abstract_tac_state(run, 1)
@@ -263,8 +263,8 @@ def _collective_deps(mode, compress, pack):
 
     args = leaves + [jnp.zeros((p,), jnp.float32) for p in plan.padded[:n_ef]]
     n_out = len(leaves) if mode == "hadronio_overlap" else 1
-    f = compat.shard_map(body, mesh=mesh, in_specs=(P(),) * len(args),
-                         out_specs=(P(),) * n_out)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * len(args),
+                      out_specs=(P(),) * n_out, check_vma=False)
     jaxpr = jax.make_jaxpr(f)(*args)
 
     inner = None
@@ -274,7 +274,7 @@ def _collective_deps(mode, compress, pack):
             break
     assert inner is not None, "no shard_map eqn found"
 
-    Literal = jax.core.Literal
+    Literal = jax.extend.core.Literal
     deps = {}
     for i, v in enumerate(inner.invars):
         deps[v] = frozenset([("leaf", i) if i < len(leaves)
@@ -326,8 +326,8 @@ def _sync_outputs(mode, comm, grads):
         return outs + efs
 
     mesh = make_mesh((1,), ("data",))
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                                 out_specs=P()))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False))
     stats = hlo.stablehlo_collective_stats(f.lower(grads).as_text())
     return f(grads), stats
 
@@ -456,13 +456,13 @@ def _sync_trace(mode, flush):
         return tuple(outs)
 
     n_out = len(leaves) if not backend.zero1 else 1
-    f = compat.shard_map(body, mesh=mesh, in_specs=(P(),) * len(leaves),
-                         out_specs=(P(),) * n_out)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * len(leaves),
+                      out_specs=(P(),) * n_out, check_vma=False)
     jaxpr = jax.make_jaxpr(f)(*leaves)
     inner = next(e for e in jaxpr.jaxpr.eqns
                  if e.primitive.name == "shard_map").params["jaxpr"]
 
-    Literal = jax.core.Literal
+    Literal = jax.extend.core.Literal
     deps = {v: frozenset([i]) for i, v in enumerate(inner.invars)}
     for v in inner.constvars:
         deps[v] = frozenset()
@@ -553,8 +553,8 @@ def test_flush_ready_first_flush_precedes_final_bucket_grad():
             outs = pipeline.finish_emission(st)
             return jnp.stack([o.reshape(-1) for o in outs])
 
-        f = compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                             out_specs=P())
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                          out_specs=P(), check_vma=False)
         jaxpr = jax.make_jaxpr(f)(jnp.ones((elems,), jnp.float32))
         inner = next(e for e in jaxpr.jaxpr.eqns
                      if e.primitive.name == "shard_map").params["jaxpr"]

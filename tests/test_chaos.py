@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import CommConfig, ModelConfig
 from repro.core.backends import SyncContext, pipeline
 from repro.launch.mesh import make_mesh
@@ -156,9 +155,9 @@ def _emit(fault):
         pipeline.set_flush_fault(fault)
     try:
         assert pipeline.flush_fault_active() == (fault is not None)
-        f = jax.jit(compat.shard_map(body, mesh=mesh,
-                                     in_specs=(P(),) * 4,
-                                     out_specs=(P(),) * 4))
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
+                                  in_specs=(P(),) * 4,
+                                  out_specs=(P(),) * 4, check_vma=False))
         return items, [np.asarray(o) for o in f(*items)]
     finally:
         pipeline.clear_flush_fault()

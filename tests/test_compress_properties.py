@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import CommConfig, RunConfig, ShapeConfig
 from repro.configs.registry import get_config
 from repro.core import aggregation as agg
@@ -96,8 +95,8 @@ def test_ef_unbiased_over_k_steps(mode, compress, pack):
         return backend.gathered_grads(r, g), r.ef
 
     ef = _zero_ef(like, comm)
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                                 out_specs=(P(), P())))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                              out_specs=(P(), P()), check_vma=False))
 
     total_in = jax.tree.map(jnp.zeros_like, like)
     total_out = jax.tree.map(jnp.zeros_like, like)

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import CommConfig
 from repro.core.backends import SyncContext, pipeline
 from repro.core.channels import ChannelFill, channel_groups
@@ -157,9 +156,9 @@ def test_incremental_staging_matches_oneshot(aggregate, flush):
 
     outs = {}
     for name, fn in [("oneshot", oneshot), ("incremental", incremental)]:
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(P(),) * len(items),
-            out_specs=(P(),) * len(items)))
+            out_specs=(P(),) * len(items), check_vma=False))
         outs[name] = f(*items)
     for a, b, x in zip(outs["oneshot"], outs["incremental"], items):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -184,8 +183,8 @@ def test_step_schedule_defers_all_flushes():
             outs = pipeline.finish_emission(st)
         return tuple(outs)
 
-    jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),) * 4,
-                             out_specs=(P(),) * 4))(*items)
+    jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 4,
+                          out_specs=(P(),) * 4, check_vma=False))(*items)
     assert seen["step"] == [[], [], [], []]
     # ready groups of 4 items on 2 channels: (0,1) and (2,3)
     assert seen["ready"] == [[], [0, 1], [], [2, 3]]
@@ -203,8 +202,8 @@ def test_finish_asserts_complete():
         return pipeline.finish_emission(st)[0]
 
     with pytest.raises(AssertionError):
-        jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P(),),
-                                 out_specs=P()))(jnp.ones((8,)))
+        jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False))(jnp.ones((8,)))
 
 
 def test_gather_flush_groups_keyed_to_schedule():

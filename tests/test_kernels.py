@@ -32,13 +32,14 @@ def test_pack_slices(n, s, wire, np_rng):
 
 
 @pytest.mark.parametrize("n,s", [(3, 4608), (5, 1536), (7, 2560),
-                                 (1, 512 * 11)])
+                                 (1, 512 * 11), (3, 512 * 129)])
 @pytest.mark.parametrize("wire", ["bfloat16", "float32"])
 def test_pack_slices_odd_alignment(n, s, wire, np_rng):
-    """Odd slice counts and 512-aligned-but-not-LANE_BLOCK-divisible
-    slice lengths (the gcd tiling path): pallas (interpret on CPU) must
-    match the jnp oracle bit-for-bit."""
-    assert s % (8 * 128 * 4) != 0        # really exercises the gcd path
+    """Odd slice counts and 512-aligned slice lengths that are no
+    multiple of 4096, including a buffer of more rows than one tile whose
+    last tile is partial: pallas (interpret on CPU) must match the jnp
+    oracle bit-for-bit."""
+    assert s % (8 * 128 * 4) != 0
     flat = jnp.asarray(np_rng.normal(size=(n * s,)), jnp.float32)
     ef = jnp.asarray(np_rng.normal(size=(n, s)) * 0.01, jnp.float32)
     w1, e1 = ops.pack_slices(flat, ef, n_slices=n, slice_elems=s,

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, obs
+from repro import obs
 from repro.configs.base import CommConfig, ModelConfig
 from repro.core.backends import SyncContext, pipeline
 from repro.launch.mesh import make_mesh
@@ -265,9 +265,9 @@ def test_leader_flush_nests_inside_local_flush():
     def body(x):
         return pipeline.emit_flat(x.reshape(-1), ctx, "all_reduce")
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
-                                 in_specs=P(("pod", "data")),
-                                 out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=P(("pod", "data")),
+                              out_specs=P(), check_vma=False))
     x = jnp.arange(96, dtype=jnp.float32).reshape(1, 96)
     with obs.capture() as rec:
         np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x[0]))

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import CommConfig, ServeConfig
 from repro.core.backends import pipeline
 from repro.core.backends.base import SyncContext
@@ -240,9 +239,9 @@ def test_leader_emission_traces_on_degenerate_pod_mesh():
     def body(x):
         return pipeline.emit_flat(x.reshape(-1), ctx, "all_reduce")
 
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
-                                 in_specs=P(("pod", "data")),
-                                 out_specs=P(), check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=P(("pod", "data")),
+                              out_specs=P(), check_vma=False))
     x = jnp.arange(1 * 37, dtype=jnp.float32).reshape(1, 37) * 0.5
     np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x[0]))
 
@@ -283,13 +282,13 @@ def test_psum_hierarchical_parity_pod():
                         .reshape(4, s))
 
         @jax.jit
-        @partial(compat.shard_map, mesh=mesh, in_specs=P(axes),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(axes),
                  out_specs=P(), check_vma=False)
         def hier(v):
             return psum_hierarchical(v.reshape(-1), "pod", "data")
 
         @jax.jit
-        @partial(compat.shard_map, mesh=mesh, in_specs=P(axes),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(axes),
                  out_specs=P(), check_vma=False)
         def flat(v):
             return jax.lax.psum(v.reshape(-1), axes)
