@@ -294,8 +294,8 @@ def main() -> int:
                         "evidence rows)")
     p.add_argument("--trace-out", default="",
                    help="write a Chrome-trace JSON of the bench's spans "
-                        "(drains, staged emissions from the dispatch-"
-                        "evidence lowering) here")
+                        "(the group's submit/join and each loop's "
+                        "drains) here")
     p.add_argument("--metrics-out", default="",
                    help="write the obs registry snapshot (poll/emission "
                         "counters of the bench run) here")
@@ -304,10 +304,6 @@ def main() -> int:
     if args.trace_out:
         from repro import obs
         obs.enable()
-        # the dispatch-evidence lowering must trace FRESH programs or a
-        # warm serve-step cache yields an emission-span-free trace
-        from repro.serving import dispatch
-        dispatch.clear_serve_step_cache()
     if args.topo:
         rows = run_topo(iters=args.iters, smoke=args.smoke)
     else:

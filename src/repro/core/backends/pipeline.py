@@ -74,7 +74,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 from dataclasses import dataclass, field
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +85,6 @@ from repro.core.flush_scheduler import (FlushPlan, make_flush_plan,
                                         make_leader_plan)
 from repro.core.hierarchical import in_group_size
 from repro.core.selector import barrier
-from repro.obs import trace as obs_trace
 
 from repro.core.backends.base import SyncContext
 
@@ -349,7 +347,6 @@ class EmitState:
     #                               in-pod intermediate (awaiting leader)
     lpad: dict = field(default_factory=dict)     # local lane id -> zero
     #                               pad added for in-pod divisibility
-    span: Any = None              # open obs emission-span token (or None)
 
 
 def _unpack_flush(buf: jax.Array, comm: CommConfig) -> jax.Array:
@@ -438,15 +435,11 @@ def _stage_local(st: EmitState, c: int, flats: list) -> None:
 
 
 def _flush_leader(st: EmitState, l: int) -> None:
-    if not obs_trace.enabled():
-        return _flush_leader_impl(st, l)
-    with obs_trace.span("leader_flush", f"lead{st.leads[l].index}",
-                        channel=st.leads[l].index,
-                        lanes=len(st.lplan.groups[l])):
-        return _flush_leader_impl(st, l)
+    with jax.named_scope(f"comm.leader.l{l}"):
+        _flush_leader_lanes(st, l)
 
 
-def _flush_leader_impl(st: EmitState, l: int) -> None:
+def _flush_leader_lanes(st: EmitState, l: int) -> None:
     """The CROSS-POD stage: ONE coalesced leader-lane collective carrying
     every parked in-pod intermediate of the local lanes assigned to
     leader ``l``, carved back per lane, then the in-pod return stage
@@ -487,15 +480,11 @@ def _flush_leader_impl(st: EmitState, l: int) -> None:
 
 
 def _flush_channel(st: EmitState, c: int) -> None:
-    if not obs_trace.enabled():
-        return _flush_channel_impl(st, c)
-    with obs_trace.span("flush", f"ch{st.chans[c].index}",
-                        channel=st.chans[c].index,
-                        items=len(st.plan.groups[c])):
-        return _flush_channel_impl(st, c)
+    with jax.named_scope(f"comm.flush.ch{st.chans[c].index}"):
+        _flush_channel_items(st, c)
 
 
-def _flush_channel_impl(st: EmitState, c: int) -> None:
+def _flush_channel_items(st: EmitState, c: int) -> None:
     """One coalesced wire flush: concatenate the channel's staged items
     into a single contiguous buffer, issue ONE collective, optionally run
     the unpack stage on the flushed buffer, carve the results back out
@@ -569,22 +558,10 @@ def begin_emission(ctx: SyncContext, n_items: int, kind: str, *,
         st.lplan = make_leader_plan(plan.n_channels, len(leads),
                                     ctx.comm.flush)
         st.lfills = [ChannelFill(frozenset(g)) for g in st.lplan.groups]
-    if obs_trace.enabled():
-        st.span = obs_trace.begin(
-            "emission", kind, items=n_items, channels=len(local),
-            leaders=len(leads), aggregate=ctx.comm.aggregate,
-            flush=ctx.comm.flush)
     return st
 
 
 def stage_slices(st: EmitState, i: int, wire: jax.Array) -> list:
-    if not obs_trace.enabled():
-        return _stage_slices_impl(st, i, wire)
-    with obs_trace.span("stage", f"item{i}", item=i):
-        return _stage_slices_impl(st, i, wire)
-
-
-def _stage_slices_impl(st: EmitState, i: int, wire: jax.Array) -> list:
     """Stage item ``i``'s wire bytes (items MUST be staged in production
     order, 0..n-1) and emit whatever that makes ready:
 
@@ -607,14 +584,15 @@ def _stage_slices_impl(st: EmitState, i: int, wire: jax.Array) -> list:
         _consult_alloc(ch.index, [x.reshape(-1)])  # per-item wire buffer
         if ch.index in st.last:
             x, _ = barrier(x, st.last[ch.index])
-        if st.kind == "all_reduce":
-            y = ch.all_reduce(x)
-        elif st.kind == "all_gather":
-            y = ch.all_gather(x.reshape(-1))
-        elif st.kind == "all_to_all":
-            y = ch.all_to_all(x.reshape(st.group, -1)).reshape(-1)
-        else:
-            y = ch.reduce_scatter(x)
+        with jax.named_scope(f"comm.flush.ch{ch.index}"):
+            if st.kind == "all_reduce":
+                y = ch.all_reduce(x)
+            elif st.kind == "all_gather":
+                y = ch.all_gather(x.reshape(-1))
+            elif st.kind == "all_to_all":
+                y = ch.all_to_all(x.reshape(st.group, -1)).reshape(-1)
+            else:
+                y = ch.reduce_scatter(x)
         st.last[ch.index] = y
         st.outs[i] = _unpack_flush(y, st.ctx.comm) if st.unpack else y
         if st.fills[c].ready:
@@ -671,9 +649,6 @@ def finish_emission(st: EmitState) -> list:
                     (l, fill.watermark)
                 _flush_leader(st, l)
     assert all(o is not None for o in st.outs), "emission incomplete"
-    if st.span is not None:
-        obs_trace.end(st.span)
-        st.span = None
     return st.outs
 
 

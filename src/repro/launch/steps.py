@@ -151,8 +151,9 @@ def make_train_step_gspmd(run: RunConfig, mesh):
     def step_fn(state: TrainState, batch: dict):
         l, aux, grads = _accumulate_grads(loss_fn, state.params, batch,
                                           run.microbatches)
-        new_params, new_opt, metrics = adamw.update(
-            grads, state.opt, state.params, run)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, metrics = adamw.update(
+                grads, state.opt, state.params, run)
         metrics = dict(metrics, loss=l)
         return TrainState(new_params, new_opt, state.step + 1), metrics
 
@@ -225,8 +226,9 @@ def make_train_step_tac(run: RunConfig, mesh):
         # (one array for global-ring keying, a pytree for per-bucket)
         ef = None if state.ef is None \
             else jax.tree.map(lambda e: e[0], state.ef)
-        res = tac.sync_grads(grads, comm, data_axis=data_axes,
-                             pod_axis=pod_axis, ef=ef)
+        with jax.named_scope("comm.sync"):
+            res = tac.sync_grads(grads, comm, data_axis=data_axes,
+                                 pod_axis=pod_axis, ef=ef)
         new_ef = None if res.ef is None \
             else jax.tree.map(lambda e: e[None], res.ef)
 
@@ -234,8 +236,9 @@ def make_train_step_tac(run: RunConfig, mesh):
         # early-slice collectives precede it in the program
         loss = jax.lax.psum(l, axes)
 
-        new_params, new_opt, metrics = backend.apply_update(
-            state.params, state.opt, res, run, uctx)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, metrics = backend.apply_update(
+                state.params, state.opt, res, run, uctx)
         metrics = dict(metrics, loss=loss)
         return TrainState(new_params, new_opt, state.step + 1,
                           new_ef), metrics

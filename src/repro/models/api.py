@@ -123,18 +123,23 @@ def loss(params: PyTree, batch: dict, cfg: ModelConfig,
         x, _ = whi.decode_stack(params, x, cfg, mode="train",
                                 cross_k=cross_k, cross_v=cross_v,
                                 shard_fn=shard_fn)
-        logits = lm_logits(params["embed"], x, shard_fn)
-        l = cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+        with jax.named_scope("lm_head"):
+            logits = lm_logits(params["embed"], x, shard_fn)
+            l = cross_entropy(logits, batch["labels"],
+                              batch.get("loss_mask"))
         return l, {"xent": l}
 
-    x, prefix = _embed_inputs(params, batch, cfg, dtype)
-    x = shard_fn(x, ("batch", "seq", None))
+    with jax.named_scope("embed"):
+        x, prefix = _embed_inputs(params, batch, cfg, dtype)
+        x = shard_fn(x, ("batch", "seq", None))
     x, _, aux = _trunk(params, x, cfg, mode="train", shard_fn=shard_fn)
-    x = apply_norm(params["ln_f"], x, cfg.norm_kind)
-    if prefix:
-        x = x[:, prefix:]
-    logits = lm_logits(params["embed"], x, shard_fn)
-    xent = cross_entropy(logits, batch["labels"], batch.get("loss_mask"))
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["ln_f"], x, cfg.norm_kind)
+        if prefix:
+            x = x[:, prefix:]
+        logits = lm_logits(params["embed"], x, shard_fn)
+        xent = cross_entropy(logits, batch["labels"],
+                             batch.get("loss_mask"))
     total = xent + aux
     return total, {"xent": xent, "aux": aux}
 
@@ -164,20 +169,23 @@ def prefill(params: PyTree, batch: dict, cfg: ModelConfig,
                                     cross_k=cross_k, cross_v=cross_v,
                                     shard_fn=shard_fn)
         cache = {"self": cache, "cross_k": cross_k, "cross_v": cross_v}
-        logits = head(params["embed"], x[:, -1:], shard_fn)[:, 0]
+        with jax.named_scope("lm_head"):
+            logits = head(params["embed"], x[:, -1:], shard_fn)[:, 0]
         return logits, cache
 
-    x, _ = _embed_inputs(params, batch, cfg, dtype)
-    x = shard_fn(x, ("batch", "seq", None))
+    with jax.named_scope("embed"):
+        x, _ = _embed_inputs(params, batch, cfg, dtype)
+        x = shard_fn(x, ("batch", "seq", None))
     x, cache, _ = _trunk(params, x, cfg, mode="prefill", shard_fn=shard_fn,
                          expert_fn=expert_fn)
-    x = apply_norm(params["ln_f"], x, cfg.norm_kind)
-    if "last_pos" in batch:     # per-request prompt end (serving engine)
-        b_idx = jnp.arange(x.shape[0])
-        x_last = x[b_idx, batch["last_pos"]][:, None]
-    else:
-        x_last = x[:, -1:]
-    logits = head(params["embed"], x_last, shard_fn)[:, 0]
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["ln_f"], x, cfg.norm_kind)
+        if "last_pos" in batch:     # per-request prompt end (serving)
+            b_idx = jnp.arange(x.shape[0])
+            x_last = x[b_idx, batch["last_pos"]][:, None]
+        else:
+            x_last = x[:, -1:]
+        logits = head(params["embed"], x_last, shard_fn)[:, 0]
     return logits, cache
 
 
@@ -208,18 +216,21 @@ def decode_step(params: PyTree, cache: PyTree, batch: dict, cfg: ModelConfig,
                                        cross_v=cache["cross_v"],
                                        shard_fn=shard_fn,
                                        cache=cache["self"], pos=pos)
-        logits = head(params["embed"], x, shard_fn)[:, 0]
+        with jax.named_scope("lm_head"):
+            logits = head(params["embed"], x, shard_fn)[:, 0]
         new_cache = dict(cache, self=new_self)
         return logits, new_cache
 
-    x = embed_tokens(params["embed"], tok, dtype)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tok, dtype)
     if cfg.family == "vlm":
         pos = pos + cfg.num_patches   # cache slots 0..P-1 hold the prefix
     x, new_cache, _ = _trunk(params, x, cfg, mode="decode",
                              shard_fn=shard_fn, cache=cache, pos=pos,
                              expert_fn=expert_fn)
-    x = apply_norm(params["ln_f"], x, cfg.norm_kind)
-    logits = head(params["embed"], x, shard_fn)[:, 0]
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["ln_f"], x, cfg.norm_kind)
+        logits = head(params["embed"], x, shard_fn)[:, 0]
     return logits, new_cache
 
 

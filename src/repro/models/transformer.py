@@ -57,47 +57,51 @@ def apply_block(p: dict, x: jax.Array, cfg: ModelConfig, *, kind: str,
                 pos: Optional[jax.Array] = None,
                 q_positions: Optional[jax.Array] = None,
                 expert_fn=None):
-    """Returns (x, new_cache_k, new_cache_v, aux_loss)."""
+    """Returns (x, new_cache_k, new_cache_v, aux_loss). The two
+    sub-blocks, norm to residual, run under the ``attn`` and ``mlp``
+    scopes (op_name metadata for the profiler's device ops)."""
     b, s, _ = x.shape
-    h = apply_norm(p["ln1"], x, cfg.norm_kind)
-    h = shard_fn(h, ("batch", "seq_gather", None))   # SP: one AG per block
-    if q_positions is None:
-        if mode != "decode":
-            q_positions = jnp.arange(s)
-        else:
-            # scalar pos -> (s,); per-request (B,) pos -> (B, s)
-            base = pos[..., None] if jnp.ndim(pos) else pos
-            q_positions = base + jnp.zeros((s,), jnp.int32)
-    q, k, v = att.project_qkv(p["attn"], h, h, q_positions, q_positions,
-                              cfg.rope_theta, shard_fn)
-    new_k = new_v = None
-    if mode == "decode":
-        out, new_k, new_v = att.decode_attend(
-            q, cache_k, cache_v, k, v, pos,
-            num_heads=cfg.num_heads, window=window, shard_fn=shard_fn)
-    else:
-        kx = att.expand_kv(k, cfg.num_heads)
-        vx = att.expand_kv(v, cfg.num_heads)
-        out = att.attend_chunked(q, kx, vx, causal=True, window=window)
-        if mode == "prefill":
-            if window > 0:     # rolling layout for windowed decode caches
-                new_k = att.to_rolling(k, window)
-                new_v = att.to_rolling(v, window)
+    with jax.named_scope("attn"):
+        h = apply_norm(p["ln1"], x, cfg.norm_kind)
+        h = shard_fn(h, ("batch", "seq_gather", None))   # SP: one AG
+        if q_positions is None:
+            if mode != "decode":
+                q_positions = jnp.arange(s)
             else:
-                new_k, new_v = k, v
-    x = x + att.out_project(p["attn"], out, shard_fn)
-    x = shard_fn(x, ("batch", "seq", None))
+                # scalar pos -> (s,); per-request (B,) pos -> (B, s)
+                base = pos[..., None] if jnp.ndim(pos) else pos
+                q_positions = base + jnp.zeros((s,), jnp.int32)
+        q, k, v = att.project_qkv(p["attn"], h, h, q_positions,
+                                  q_positions, cfg.rope_theta, shard_fn)
+        new_k = new_v = None
+        if mode == "decode":
+            out, new_k, new_v = att.decode_attend(
+                q, cache_k, cache_v, k, v, pos,
+                num_heads=cfg.num_heads, window=window, shard_fn=shard_fn)
+        else:
+            kx = att.expand_kv(k, cfg.num_heads)
+            vx = att.expand_kv(v, cfg.num_heads)
+            out = att.attend_chunked(q, kx, vx, causal=True, window=window)
+            if mode == "prefill":
+                if window > 0:   # rolling layout for windowed decode caches
+                    new_k = att.to_rolling(k, window)
+                    new_v = att.to_rolling(v, window)
+                else:
+                    new_k, new_v = k, v
+        x = x + att.out_project(p["attn"], out, shard_fn)
+        x = shard_fn(x, ("batch", "seq", None))
 
-    h = apply_norm(p["ln2"], x, cfg.norm_kind)
-    h = shard_fn(h, ("batch", "seq_gather", None))   # SP: one AG per block
-    aux = jnp.zeros((), jnp.float32)
-    if kind == "moe":
-        y, aux = moe_mod.apply_moe(p["moe"], h, cfg, shard_fn,
-                                   expert_fn=expert_fn)
-    else:
-        y = apply_mlp(p["mlp"], h, cfg.mlp_kind, shard_fn)
-    x = x + y
-    x = shard_fn(x, ("batch", "seq", None))
+    with jax.named_scope("mlp"):
+        h = apply_norm(p["ln2"], x, cfg.norm_kind)
+        h = shard_fn(h, ("batch", "seq_gather", None))   # SP: one AG
+        aux = jnp.zeros((), jnp.float32)
+        if kind == "moe":
+            y, aux = moe_mod.apply_moe(p["moe"], h, cfg, shard_fn,
+                                       expert_fn=expert_fn)
+        else:
+            y = apply_mlp(p["mlp"], h, cfg.mlp_kind, shard_fn)
+        x = x + y
+        x = shard_fn(x, ("batch", "seq", None))
     return x, new_k, new_v, aux
 
 
@@ -118,7 +122,9 @@ def apply_stack(params: dict, x: jax.Array, cfg: ModelConfig, *, kind: str,
                 pos: Optional[jax.Array] = None,
                 q_positions: Optional[jax.Array] = None,
                 expert_fn=None):
-    """Scan the block over stacked params.
+    """Scan the block over stacked params, under the ``layers`` scope
+    (the loop's own work: slicing the stacked params and caches, and
+    stacking each layer's outputs and gradients).
 
     Returns (x, new_cache, aux_sum). ``cache`` is {"k","v"}: (L,B,S,KV,Dh)
     for prefill/decode; None in train mode.
@@ -145,13 +151,13 @@ def apply_stack(params: dict, x: jax.Array, cfg: ModelConfig, *, kind: str,
 
     from repro.models.unroll import scan_or_unroll
     L = cfg.num_layers
-    if mode == "train":
-        body = jax.checkpoint(body)
-        x, aux = scan_or_unroll(body, x, params, L)
-        return x, None, jnp.sum(aux)
-    if mode == "prefill":
-        x, (ks, vs, aux) = scan_or_unroll(body, x, params, L)
+    with jax.named_scope("layers"):
+        if mode == "train":
+            x, aux = scan_or_unroll(jax.checkpoint(body), x, params, L)
+            return x, None, jnp.sum(aux)
+        if mode == "prefill":
+            x, (ks, vs, aux) = scan_or_unroll(body, x, params, L)
+            return x, {"k": ks, "v": vs}, jnp.sum(aux)
+        x, (ks, vs, aux) = scan_or_unroll(
+            body, x, (params, cache["k"], cache["v"]), L)
         return x, {"k": ks, "v": vs}, jnp.sum(aux)
-    x, (ks, vs, aux) = scan_or_unroll(body, x,
-                                      (params, cache["k"], cache["v"]), L)
-    return x, {"k": ks, "v": vs}, jnp.sum(aux)

@@ -7,8 +7,9 @@ Three layers (docs/OBSERVABILITY.md):
   the pull-based ``publish_*`` adapters that scrape every subsystem's
   existing ad-hoc counters into one ``snapshot()``.
 * :mod:`repro.obs.trace` — ring-buffered nested span tracing over the
-  staged emission API and the serving plane, Chrome-trace/Perfetto
-  export, zero overhead when disabled.
+  serving plane, mirrored into the ``jax.profiler`` trace as
+  ``repro.<kind>`` annotations, Chrome-trace/Perfetto export, zero
+  overhead when disabled.
 * :mod:`repro.obs.baseline` — the perf-regression gate comparing two
   ``BENCH_*.json`` artifacts with per-metric tolerance bands (CLI:
   ``benchmarks/bench_diff.py``).

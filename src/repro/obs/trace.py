@@ -1,45 +1,37 @@
 """Span tracing for the Observatory telemetry plane.
 
-The paper's evaluation hinges on seeing WHERE time goes inside the
-transport (per-connection RTT structure, buffer-fill behavior, poll
-strategy effects — Figs. 5-8), and the same group's benchmark suite
-(arXiv:1910.02245) instruments exactly those seams. This module is the
-repro's equivalent: NESTED SPANS over the staged emission API
-(``begin_emission`` -> ``stage_slices`` -> ``flush_ready`` ->
-``finish_emission``, leader flushes, the a2a expert exchange) and the
-serving plane (admission -> prefill -> decode waves, event-loop drains,
-supervisor heal windows), recorded into a RING-BUFFERED
-:class:`TraceRecorder` and exported as Chrome-trace / Perfetto JSON
-(``chrome://tracing`` / https://ui.perfetto.dev load it directly).
+Nested host spans over the serving plane (event-loop drains, waves,
+prefill, the decode step and the host work between steps, admission,
+each finished request's timeline, supervisor heal windows), recorded into
+a ring-buffered :class:`TraceRecorder` and exported as Chrome-trace /
+Perfetto JSON (``launch/serve.py --trace-out``).
 
 Design rules:
 
-* **Zero overhead when disabled.** The module-level gate is one global:
-  ``enabled()`` is a ``None`` check, :func:`span` returns a shared
-  ``nullcontext`` and :func:`begin` returns ``None`` without touching a
-  clock. Instrumentation sites on hot paths guard with ``if
-  trace.enabled():`` so the disabled cost is a single load+compare.
-* **Observation only.** Spans record host-side wall-clock around work
-  that already happens; nothing in this module feeds back into emission
-  structure, scheduling, or numerics — which is why telemetry-enabled
-  runs serve bit-identical tokens (tested).
-* **Trace-time vs run-time spans.** Emission/flush/stage spans fire when
-  a program is TRACED (first compile of a serve step); steady-state
-  decode steps replay the compiled program and record only the serving
-  plane's spans (decode/admission/drain). A run that never traces a
-  fresh program legitimately has no emission spans — clear the
-  serve-step cache (``serving/dispatch.clear_serve_step_cache``) when
-  you need them.
-* **Thread safety.** Each thread keeps its own span stack (nesting is a
-  per-thread property — threaded drains interleave); the span ring and
-  the tid table are lock-protected. ``complete()`` records a span from
-  explicit timestamps without touching any stack — the supervisor's
-  detect->heal windows use it.
+* **One span system, on the profiler's clock.** While a recorder is
+  enabled every span also opens a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<kind>`` whose keyword arguments are the span's ``name`` and
+  args: under ``jax.profiler.trace`` each span is a ``/host:CPU`` event
+  with those args as stats, on the same clock as the device's ops.
+  ``complete()`` (spans from explicit stamps) leaves a zero-length event
+  at the call, carrying the span's length as ``dur_ms``.
+* **Zero overhead when disabled.** ``enabled()`` is a ``None`` check,
+  :func:`span` returns a shared ``nullcontext`` and :func:`begin` returns
+  ``None`` without touching a clock or the profiler.
+* **Observation only.** Nothing here feeds back into scheduling or
+  numerics, so traced runs serve bit-identical tokens (tested). The
+  device work has no spans: the model and the comm layer name it with
+  ``jax.named_scope`` (``attn``, ``comm.flush.ch<i>`` ...), which lands in
+  each HLO op's ``op_name`` and costs nothing at run time.
+* **Thread safety.** Each thread keeps its own span stack; a span's
+  ``parent`` is the kind of the span enclosing it on its thread. The ring
+  and the tid table are lock-protected.
 
-Span kinds in the shipped instrumentation (docs/OBSERVABILITY.md):
-``emission`` / ``stage`` / ``flush`` / ``leader_flush`` (pipeline.py),
-``build`` (dispatch.py), ``prefill`` / ``decode`` / ``admission``
-(engine.py), ``drain`` (event_loop.py), ``heal`` (supervisor.py).
+Span kinds (``KINDS``, docs/OBSERVABILITY.md): ``drain`` (event_loop.py),
+``submit`` / ``join`` (the group's side of ``EventLoopGroup.submit`` and
+``run``), ``wave_setup`` / ``prefill`` / ``grow_cache`` / ``decode`` /
+``host_step`` / ``admission`` / ``request`` (engine.py), ``heal``
+(supervisor.py).
 """
 from __future__ import annotations
 
@@ -51,17 +43,20 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-# The instrumented span kinds (open set — the recorder accepts any string;
-# this tuple is the documented taxonomy the smoke assertions key on).
-KINDS = ("emission", "stage", "flush", "leader_flush", "build",
-         "prefill", "decode", "admission", "drain", "heal")
+from jax.profiler import TraceAnnotation
+
+# The instrumented span kinds (the recorder accepts any string; this is
+# the documented taxonomy the smoke assertions key on).
+KINDS = ("drain", "submit", "join", "wave_setup", "prefill", "grow_cache",
+         "decode", "host_step", "admission", "request", "heal")
 
 
 @dataclass
 class Span:
     """One closed span. Times are seconds relative to the recorder's
     epoch (``perf_counter`` at construction); ``depth`` is the nesting
-    depth at close time on the recording thread (0 = top level)."""
+    depth at close time on the recording thread (0 = top level) and
+    ``parent`` the kind of the span that enclosed it ("" at top level)."""
     kind: str
     name: str
     t0: float
@@ -69,6 +64,7 @@ class Span:
     tid: int
     depth: int
     args: dict = field(default_factory=dict)
+    parent: str = ""
 
     @property
     def t1(self) -> float:
@@ -78,13 +74,10 @@ class Span:
 class TraceRecorder:
     """Ring-buffered span recorder with per-thread nesting stacks.
 
-    ``capacity`` bounds the ring: the oldest span is evicted per
-    overflowing append and counted in ``dropped`` (long-running serves
-    must never grow memory unboundedly — same rule as the evidence
-    RingLogs). ``forced_closes`` counts non-LIFO closes (an ``end``
-    whose token was not on top — intermediates are force-closed so the
-    trace stays an interval forest); a well-formed run keeps it at 0.
-    """
+    ``capacity`` bounds the ring: the oldest span is evicted and counted
+    in ``dropped``. ``forced_closes`` counts non-LIFO closes (an ``end``
+    whose token was not on top: the spans above it are force-closed so the
+    trace stays an interval forest); a well-formed run keeps it at 0."""
 
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
@@ -121,17 +114,20 @@ class TraceRecorder:
     # -- the span API ---------------------------------------------------
 
     def begin(self, kind: str, name: str = "", **args) -> list:
-        """Open a span; returns an opaque token for :meth:`end`."""
-        tok = [kind, name, self._now(), args]
-        self._stack().append(tok)
+        """Open a span and its profiler annotation; returns an opaque
+        token for :meth:`end`."""
+        st = self._stack()
+        ann = TraceAnnotation("repro." + kind, name=name, **args)
+        ann.__enter__()
+        tok = [kind, name, self._now(), args, st[-1][0] if st else "", ann]
+        st.append(tok)
         return tok
 
     def end(self, token: Optional[list] = None, **extra) -> Optional[Span]:
         """Close the span ``token`` (or the top of this thread's stack).
-        A non-LIFO token force-closes the intermediates above it (counted
-        in ``forced_closes``); a token that is not on this thread's
-        stack at all is counted and ignored — ends must never raise on
-        the serving path."""
+        A non-LIFO token force-closes the spans above it; a token not on
+        this thread's stack is counted and ignored (both in
+        ``forced_closes``): ends never raise on the serving path."""
         st = self._stack()
         if token is not None and not any(t is token for t in st):
             self.forced_closes += 1
@@ -157,20 +153,26 @@ class TraceRecorder:
     def complete(self, kind: str, name: str, t0_s: float, t1_s: float,
                  **args) -> Span:
         """Record a span from explicit ``perf_counter`` stamps, bypassing
-        the nesting stacks (the supervisor's detect->heal windows carry
-        their own ``t_detect``/``t_heal``)."""
-        sp = Span(kind, name, t0_s - self._epoch,
-                  max(0.0, t1_s - t0_s), self._tid(),
-                  depth=len(self._stack()), args=dict(args))
+        the nesting stacks (heal windows, request timelines)."""
+        dur = max(0.0, t1_s - t0_s)
+        with TraceAnnotation("repro." + kind, name=name, dur_ms=dur * 1e3,
+                             **args):
+            pass
+        st = self._stack()
+        sp = Span(kind, name, t0_s - self._epoch, dur, self._tid(),
+                  depth=len(st), args=dict(args),
+                  parent=st[-1][0] if st else "")
         self._append(sp)
         return sp
 
     def _emit(self, tok: list, extra: dict) -> Span:
-        kind, name, t0, args = tok
+        kind, name, t0, args, parent, ann = tok
         if extra:
             args = {**args, **extra}
+            ann.set_metadata(**extra)
+        ann.__exit__(None, None, None)
         sp = Span(kind, name, t0, self._now() - t0, self._tid(),
-                  depth=len(self._stack()), args=args)
+                  depth=len(self._stack()), args=args, parent=parent)
         self._append(sp)
         return sp
 
@@ -225,9 +227,8 @@ class TraceRecorder:
 
 def well_formed(rec: TraceRecorder) -> tuple:
     """``(ok, problems)``: every opened span closed, no forced closes,
-    and — per thread — spans form a proper interval forest (children
-    contained in their parents; the span-tree contract the tests and the
-    obs-smoke CI job assert)."""
+    and per thread a proper interval forest (children inside their
+    parents; the contract the tests and the obs-smoke CI job assert)."""
     problems: list = []
     open_ = rec.open_spans()
     if open_:
@@ -254,8 +255,8 @@ def well_formed(rec: TraceRecorder) -> tuple:
 
 def containing(rec: TraceRecorder, inner: Span, kind: str) -> Optional[Span]:
     """The tightest span of ``kind`` (same thread) whose interval
-    contains ``inner`` — nesting queries for tests ("every leader flush
-    sits inside a local flush span")."""
+    contains ``inner`` — nesting queries for tests ("every admission
+    sits inside a host step")."""
     eps = 1e-9
     best = None
     for s in rec.spans:
@@ -268,9 +269,8 @@ def containing(rec: TraceRecorder, inner: Span, kind: str) -> Optional[Span]:
 
 
 # ---------------------------------------------------------------------------
-# The module-level gate (obs.enabled()). One global; every instrumentation
-# site either checks enabled() explicitly or calls span()/begin()/end()/
-# complete(), which no-op on the disabled path without touching a clock.
+# The module-level gate (obs.enabled()): span()/begin()/end()/complete()
+# no-op on the disabled path without touching a clock.
 # ---------------------------------------------------------------------------
 
 _RECORDER: Optional[TraceRecorder] = None
@@ -309,8 +309,8 @@ def span(kind: str, name: str = "", **args):
 
 
 def begin(kind: str, name: str = "", **args):
-    """Token-style open (for spans that straddle function boundaries,
-    e.g. ``begin_emission`` -> ``finish_emission``); None when disabled."""
+    """Token-style open (for spans that straddle loop iterations, such as
+    the engine's decode step); None when disabled."""
     rec = _RECORDER
     return None if rec is None else rec.begin(kind, name, **args)
 

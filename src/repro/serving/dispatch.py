@@ -55,7 +55,6 @@ from repro.core.backends import get_backend
 from repro.core.backends.base import SyncContext
 from repro.launch.mesh import make_mesh
 from repro.models import api
-from repro.obs import trace as obs_trace
 from repro.models import moe as moe_mod
 from repro.models.layers import no_shard
 from repro.serving import cache_layout
@@ -99,20 +98,6 @@ def clear_serve_step_cache() -> None:
 
 
 def make_serve_step(cfg: ModelConfig, comm: CommConfig, mesh=None, *,
-                    channel_indices: Optional[tuple] = None,
-                    pod_axis: Optional[str] = None) -> ServeStep:
-    if not obs_trace.enabled():
-        return _make_serve_step(cfg, comm, mesh,
-                                channel_indices=channel_indices,
-                                pod_axis=pod_axis)
-    with obs_trace.span("build", f"serve_step:{cfg.name}",
-                        mode=comm.mode, channels=comm.channels):
-        return _make_serve_step(cfg, comm, mesh,
-                                channel_indices=channel_indices,
-                                pod_axis=pod_axis)
-
-
-def _make_serve_step(cfg: ModelConfig, comm: CommConfig, mesh=None, *,
                      channel_indices: Optional[tuple] = None,
                      pod_axis: Optional[str] = None) -> ServeStep:
     """Build the TAC serve step for one (model, comm, mesh, affinity)
@@ -234,33 +219,35 @@ def _make_serve_step(cfg: ModelConfig, comm: CommConfig, mesh=None, *,
         if pure_local:
             return logits, cache       # pure local reference, nothing to wire
 
-        # ONE gathering write for the whole prefill result: every cache
-        # leaf + the last-token logits coalesced into a single flat f32
-        # payload, gathered peer-major, carved back per leaf with the
-        # batch axis re-merged (slot k of the full batch = peer k//bs,
-        # local row k%bs — matching the engine's row padding).
-        leaves, treedef = jax.tree.flatten((cache, logits))
-        flats = [l.astype(jnp.float32).reshape(-1) for l in leaves]
-        sizes = [f.shape[0] for f in flats]
-        wire = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-        g = backend.serve_emit(wire, ctx, "all_gather").reshape(n_shards, -1)
+        with jax.named_scope("comm.gather"):
+            # ONE gathering write for the whole prefill result: every
+            # cache leaf + the last-token logits coalesced into a single
+            # flat f32 payload, gathered peer-major, carved back per leaf
+            # with the batch axis re-merged (slot k of the full batch =
+            # peer k//bs, local row k%bs — the engine's row padding).
+            leaves, treedef = jax.tree.flatten((cache, logits))
+            flats = [l.astype(jnp.float32).reshape(-1) for l in leaves]
+            sizes = [f.shape[0] for f in flats]
+            wire = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
+            g = backend.serve_emit(wire, ctx, "all_gather").reshape(
+                n_shards, -1)
 
-        outs, off = [], 0
-        # flatten order: cache leaves then logits. Each cache leaf's
-        # batch axis is the family's DECLARED layout (cache_layout.py);
-        # the logits row always merges at axis 0 (this layer's own
-        # output contract, not a family fact).
-        bas = cache_layout.batch_axes(cfg.family, cache) + [0]
-        assert len(bas) == len(leaves), (len(bas), len(leaves))
-        for leaf, n, ba in zip(leaves, sizes, bas):
-            seg = g[:, off:off + n].reshape((n_shards,) + leaf.shape)
-            off += n
-            m = jnp.moveaxis(seg, 0, ba)
-            shape = leaf.shape
-            merged = m.reshape(shape[:ba] + (n_shards * shape[ba],)
-                               + shape[ba + 1:])
-            outs.append(merged.astype(leaf.dtype))
-        full_cache, full_logits = jax.tree.unflatten(treedef, outs)
+            outs, off = [], 0
+            # flatten order: cache leaves then logits. Each cache leaf's
+            # batch axis is the family's DECLARED layout (cache_layout.py);
+            # the logits row always merges at axis 0 (this layer's own
+            # output contract, not a family fact).
+            bas = cache_layout.batch_axes(cfg.family, cache) + [0]
+            assert len(bas) == len(leaves), (len(bas), len(leaves))
+            for leaf, n, ba in zip(leaves, sizes, bas):
+                seg = g[:, off:off + n].reshape((n_shards,) + leaf.shape)
+                off += n
+                m = jnp.moveaxis(seg, 0, ba)
+                shape = leaf.shape
+                merged = m.reshape(shape[:ba] + (n_shards * shape[ba],)
+                                   + shape[ba + 1:])
+                outs.append(merged.astype(leaf.dtype))
+            full_cache, full_logits = jax.tree.unflatten(treedef, outs)
         return full_logits, full_cache
 
     # -- replicated decode + TP logit reduction -------------------------

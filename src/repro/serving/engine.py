@@ -32,6 +32,7 @@ Mechanics:
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import defaultdict, deque
 from typing import Any, Optional, Sequence
 
@@ -65,6 +66,10 @@ class Request:
     #                               ServeConfig.tenants entry ("" rides
     #                               the first tenant; single-tenant
     #                               groups ignore it)
+    t_submit: Optional[float] = dataclasses.field(default=None,
+                                                  compare=False)
+    #                               perf_counter at EventLoopGroup.submit
+    #                               (the engine's entry if never submitted)
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32)
@@ -72,19 +77,38 @@ class Request:
 
 @dataclasses.dataclass
 class Result:
+    """A finished request and its timeline, all on ``time.perf_counter``:
+    submission, the host-ready time of its first token, completion, and
+    the ready time of each generated token (``token_times[0] ==
+    t_first`` when it generated any)."""
     uid: int
     tokens: np.ndarray            # generated tokens (<= max_new)
     prompt_len: int
     steps: int
+    t_submit: float = dataclasses.field(default=0.0, compare=False)
+    t_first: float = dataclasses.field(default=0.0, compare=False)
+    t_done: float = dataclasses.field(default=0.0, compare=False)
+    token_times: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros((0,)), compare=False)
 
 
 @dataclasses.dataclass
 class _Slot:
-    """One in-flight request occupying a decode slot."""
+    """One in-flight request occupying a decode slot. Its tokens after
+    the first are read at loop steps ``admitted_step`` on (the wave's
+    ``ready`` times); ``t_first`` is set when admission sampled its
+    first token from its own prefill."""
     req: Request
     admitted_step: int
     toks: list
     done: bool = False
+    t_first: Optional[float] = None
+
+    def token_times(self, ready: list) -> np.ndarray:
+        lead = [] if self.t_first is None else [self.t_first]
+        n = len(self.toks) - len(lead)
+        return np.asarray(lead + ready[self.admitted_step:
+                                       self.admitted_step + n])
 
 
 class DecodeEngine:
@@ -103,8 +127,9 @@ class DecodeEngine:
                  rng: Optional[jax.Array] = None,
                  serve: Optional[ServeConfig] = None, mesh=None,
                  channel_indices: Optional[tuple] = None,
-                 poller: Optional[Poller] = None):
+                 poller: Optional[Poller] = None, loop_index: int = 0):
         self.cfg = cfg
+        self.loop_index = loop_index      # the owning event loop (spans)
         self.params = params
         self.max_batch = max_batch
         self.max_len = max_len
@@ -174,6 +199,10 @@ class DecodeEngine:
 
     def generate(self, reqs: Sequence[Request]) -> list:
         reqs = list(reqs)
+        now = time.perf_counter()
+        for r in reqs:
+            if r.t_submit is None:
+                r.t_submit = now
         results: list = []
         if self._recurrent:
             # equal-length buckets; no mid-flight admission (the
@@ -210,27 +239,24 @@ class DecodeEngine:
         b = len(initial)
         R = self.n_shards
         b_pad = max(R, -(-b // R) * R)    # rows padded to the ring size
-        lens = np.zeros((b_pad,), np.int32)
-        for i, r in enumerate(initial):
-            lens[i] = len(r.prompt)
-        pad_to = int(lens.max())
-        assert pad_to + max(r.max_new for r in initial) <= self.max_len, \
-            "prompt + max_new exceeds engine max_len"
-        toks = np.zeros((b_pad, pad_to), np.int32)
-        for i, r in enumerate(initial):
-            toks[i, : lens[i]] = r.prompt
+        with obs_trace.span("wave_setup", f"wave_b{b}", batch=b):
+            lens = np.zeros((b_pad,), np.int32)
+            for i, r in enumerate(initial):
+                lens[i] = len(r.prompt)
+            pad_to = int(lens.max())
+            assert pad_to + max(r.max_new for r in initial) \
+                <= self.max_len, "prompt + max_new exceeds engine max_len"
+            toks = np.zeros((b_pad, pad_to), np.int32)
+            for i, r in enumerate(initial):
+                toks[i, : lens[i]] = r.prompt
 
-        if obs_trace.enabled():
-            with obs_trace.span("prefill", f"wave_b{b}", batch=b,
-                                pad_to=pad_to):
-                logits, cache = self._prefill(
-                    self.params, self._prefill_batch(toks, lens))
-                self.poller.wait(logits)
-        else:
+        with obs_trace.span("prefill", f"wave_b{b}", batch=b,
+                            pad_to=pad_to):
             logits, cache = self._prefill(self.params,
                                           self._prefill_batch(toks, lens))
             self.poller.wait(logits)
-        cache = api.grow_cache(self.cfg, cache, self.max_len)
+        with obs_trace.span("grow_cache", f"wave_b{b}", batch=b):
+            cache = api.grow_cache(self.cfg, cache, self.max_len)
 
         slots: list = [_Slot(r, 0, []) for r in initial] \
             + [None] * (b_pad - b)
@@ -241,66 +267,83 @@ class DecodeEngine:
         tok = self._sample(logits, temps)
         steps = 0
         results: list = []
-
-        while True:
-            # flush boundary: the staged emission's channel flushes for
-            # this step are complete once the sampled tokens are ready
-            self.poller.wait(tok)
-            tok_np = np.asarray(tok)
-            for i, s in enumerate(slots):
-                if s is None:
-                    continue
-                if s.req.max_new > 0:    # max_new=0: prefill-only, no token
-                    s.toks.append(int(tok_np[i]))
-                    if self.eos_id is not None \
-                            and s.toks[-1] == self.eos_id:
+        ready: list = []                  # host-ready time of each loop
+        #                                   step's tokens (one clock read)
+        span = None                       # the open decode or host_step
+        try:
+            while True:
+                # flush boundary: the staged emission's channel flushes
+                # for this step are complete once the sampled tokens are
+                # ready. The decode span ends here and the host step
+                # (token read, slot bookkeeping, admission) begins.
+                self.poller.wait(tok)
+                now = time.perf_counter()
+                ready.append(now)
+                obs_trace.end(span)
+                span = obs_trace.begin("host_step", f"step{steps}",
+                                       loop=self.loop_index, step=steps)
+                tok_np = np.asarray(tok)
+                for i, s in enumerate(slots):
+                    if s is None:
+                        continue
+                    if s.req.max_new > 0:  # max_new=0: prefill-only
+                        s.toks.append(int(tok_np[i]))
+                        if self.eos_id is not None \
+                                and s.toks[-1] == self.eos_id:
+                            s.done = True
+                    if len(s.toks) >= s.req.max_new:
                         s.done = True
-                if len(s.toks) >= s.req.max_new:
-                    s.done = True
-                if s.done:
-                    results.append(Result(
-                        uid=s.req.uid,
-                        tokens=np.asarray(s.toks, np.int64),
-                        prompt_len=len(s.req.prompt),
-                        steps=steps + 1 - s.admitted_step))
-                    slots[i] = None
-            steps += 1
-            # the flush-boundary fault window: storm requests injected
-            # here enter the run queue like any client's and are admitted
-            # (or queued) by the very same admission path below — per-row
-            # exactness keeps the residents' tokens bit-identical. The
-            # gate sees the burst AFTER the hook, so supervisor
-            # backpressure composes with chaos storms.
-            if not self._recurrent and (self.admission_hook is not None
-                                        or self.admission_gate is not None):
-                extra = list(self.admission_hook(self, steps) or []) \
-                    if self.admission_hook is not None else []
-                if self.admission_gate is not None:
-                    extra = self.admission_gate(self, steps, extra)
-                if extra:
+                    if s.done:
+                        times = s.token_times(ready)
+                        results.append(_result(
+                            s.req, s.toks, steps + 1 - s.admitted_step,
+                            float(times[0]) if len(times) else now, now,
+                            times))
+                        slots[i] = None
+                steps += 1
+                # the flush-boundary fault window: storm requests
+                # injected here enter the run queue like any client's and
+                # are admitted (or queued) by the very same admission
+                # path below — per-row exactness keeps the residents'
+                # tokens bit-identical. The gate sees the burst AFTER the
+                # hook, so supervisor backpressure composes with storms.
+                if not self._recurrent and (
+                        self.admission_hook is not None
+                        or self.admission_gate is not None):
+                    extra = list(self.admission_hook(self, steps) or []) \
+                        if self.admission_hook is not None else []
+                    if self.admission_gate is not None:
+                        extra = self.admission_gate(self, steps, extra)
+                    for r in extra:
+                        if r.t_submit is None:
+                            r.t_submit = now
                     pending.extend(extra)
-            # continuous batching: admit from the run queue into freed
-            # slots, at this flush boundary — ONE batched prefill over
-            # every freed slot (solo == batched bit-for-bit; exactness is
-            # per-row). Only the first max_batch slots are
-            # admission-eligible — ring-padding rows beyond the
-            # configured bound carry no requests (max_batch stays the
-            # true per-loop in-flight limit even when b_pad > max_batch).
-            if pending and not self._recurrent:
-                tok, cache, pos = self._admit_ready(
-                    pending, cache, pos, temps, tok, steps, slots, results)
-            if not any(s is not None for s in slots) and not pending:
-                break
-            active = np.array([s is not None for s in slots])
-            dec = {"token": tok, "pos": pos}
-            if obs_trace.enabled():
-                with obs_trace.span("decode", f"step{steps}", step=steps,
-                                    active=int(active.sum())):
-                    logits, cache = self._decode(self.params, cache, dec)
-            else:
-                logits, cache = self._decode(self.params, cache, dec)
-            tok = self._sample(logits, temps)
-            pos = jnp.where(jnp.asarray(active), pos + 1, pos)
+                # continuous batching: admit from the run queue into
+                # freed slots, at this flush boundary — ONE batched
+                # prefill over every freed slot (solo == batched
+                # bit-for-bit; exactness is per-row). Only the first
+                # max_batch slots are admission-eligible — ring-padding
+                # rows beyond the configured bound carry no requests.
+                if pending and not self._recurrent:
+                    tok, cache, pos = self._admit_ready(
+                        pending, cache, pos, temps, tok, steps, slots,
+                        results)
+                if not any(s is not None for s in slots) and not pending:
+                    break
+                active = np.array([s is not None for s in slots])
+                obs_trace.end(span)
+                # the decode span runs from this enqueue to the moment
+                # its sampled tokens are ready (the wait above)
+                span = obs_trace.begin(
+                    "decode", f"step{steps}", loop=self.loop_index,
+                    step=steps, active=int(active.sum())) \
+                    if obs_trace.enabled() else None
+                logits, cache = self._decode(self.params, cache,
+                                             {"token": tok, "pos": pos})
+                tok = self._sample(logits, temps)
+                pos = jnp.where(jnp.asarray(active), pos + 1, pos)
+        finally:
+            obs_trace.end(span)
         return results
 
     def _admit_ready(self, pending: deque, cache: PyTree, pos: jax.Array,
@@ -374,28 +417,26 @@ class DecodeEngine:
         self.admit_prefills += 1
         t_arr = self._sample(logits1, rtemps)
         t_np = np.asarray(t_arr)
+        now = time.perf_counter()         # the first tokens are ready
         live_rows: list = []
         live_slots: list = []
         for row, (i, req) in enumerate(zip(free, reqs)):
             t0 = int(t_np[row])
-            plen = int(lens[row])
             if req.max_new <= 0:          # prefill-only: zero tokens
-                results.append(Result(uid=req.uid,
-                                      tokens=np.asarray([], np.int64),
-                                      prompt_len=plen, steps=0))
+                results.append(_result(req, [], 0, now, now, np.zeros(0)))
                 continue
             if (self.eos_id is not None and t0 == self.eos_id) \
                     or req.max_new == 1:  # finished at its first token
-                results.append(Result(uid=req.uid,
-                                      tokens=np.asarray([t0], np.int64),
-                                      prompt_len=plen, steps=1))
+                results.append(_result(req, [t0], 1, now, now,
+                                       np.asarray([now])))
                 continue
             live_rows.append(row)
             live_slots.append(i)
             temps[i] = req.temperature
-            slots[i] = _Slot(req, steps, [t0])
+            slots[i] = _Slot(req, steps, [t0], t_first=now)
         if live_rows:
-            cache1 = api.grow_cache(self.cfg, cache1, self.max_len)
+            with obs_trace.span("grow_cache", f"admit{k}", batch=k):
+                cache1 = api.grow_cache(self.cfg, cache1, self.max_len)
             rsel = np.asarray(live_rows)
             ssel = np.asarray(live_slots)
             # attention caches carry batch at axis 1 (L, B, S, KV, Dh)
@@ -404,6 +445,21 @@ class DecodeEngine:
             tok = tok.at[ssel].set(t_arr[rsel])
             pos = pos.at[ssel].set(jnp.asarray(lens[rsel]))
         return tok, cache, pos
+
+
+def _result(req: Request, toks: list, steps: int, t_first: float,
+            t_done: float, times: np.ndarray) -> Result:
+    """A finished request's Result; with the recorder on, also its
+    zero-length ``request`` event carrying the timeline."""
+    t_sub = req.t_submit
+    if obs_trace.enabled():
+        obs_trace.complete("request", f"uid{req.uid}", t_done, t_done,
+                           uid=req.uid, ttft_ms=(t_first - t_sub) * 1e3,
+                           done_ms=(t_done - t_sub) * 1e3,
+                           tokens=len(toks))
+    return Result(uid=req.uid, tokens=np.asarray(toks, np.int64),
+                  prompt_len=len(req.prompt), steps=steps, t_submit=t_sub,
+                  t_first=t_first, t_done=t_done, token_times=times)
 
 
 # ---------------------------------------------------------------------------
@@ -514,7 +570,7 @@ def make_engine_group(cfg: ModelConfig, params: PyTree, serve: ServeConfig,
                            max_len=serve.max_len, eos_id=eos_id,
                            rng=jax.random.PRNGKey(seed + i), serve=serve,
                            mesh=mesh, channel_indices=chans,
-                           poller=loop.poller)
+                           poller=loop.poller, loop_index=i)
         loop.engine = eng
         loop.runner = lambda _loop, items, eng=eng: eng.generate(items)
         loops.append(loop)

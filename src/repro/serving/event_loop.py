@@ -348,6 +348,14 @@ class EventLoopGroup:
         stride order (see the class docstring)."""
         if not isinstance(items, (list, tuple)):
             items = [items]
+        with obs_trace.span("submit", f"n{len(items)}", items=len(items)):
+            self._submit(items)
+
+    def _submit(self, items) -> None:
+        now = time.perf_counter()
+        for it in items:                  # the request timeline's start
+            if hasattr(it, "t_submit"):
+                it.t_submit = now
         if not self.tenants:
             for it in items:
                 self.loops[self._rr % self.n_loops].submit(it)
@@ -403,21 +411,25 @@ class EventLoopGroup:
                   for l in self.loops]
             for t in ts:
                 t.start()
-            for t in ts:
-                t.join()
+            with obs_trace.span("join", f"loops{self.n_loops}",
+                                loops=self.n_loops):
+                for t in ts:
+                    t.join()
             failed = [l for l in self.loops if l.error is not None]
             for l in failed:
                 self._record_failure(l)
             if failed and raise_on_failure:
                 raise failed[0].error
         else:
-            for l in self.loops:
-                try:
-                    l.drain()
-                except BaseException:
-                    self._record_failure(l)
-                    if raise_on_failure:
-                        raise
+            with obs_trace.span("join", f"loops{self.n_loops}",
+                                loops=self.n_loops):
+                for l in self.loops:
+                    try:
+                        l.drain()
+                    except BaseException:
+                        self._record_failure(l)
+                        if raise_on_failure:
+                            raise
         return [r for l in self.loops for r in l.results]
 
     def poll_stats(self) -> PollStats:

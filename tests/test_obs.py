@@ -5,17 +5,21 @@ Four contracts under test (docs/OBSERVABILITY.md):
 * REGISTRY — typed metrics with the closed label taxonomy, snapshot
   sections split by determinism class, byte-stable JSON, RingLog
   bounds, the scoped emission-stats seam.
-* SPANS — the staged emission + serving plane instrumentation yields a
-  WELL-FORMED interval forest (every ``begin_emission`` closed, leader
-  flushes nested in their lane's local flush), exports as loadable
-  Chrome-trace JSON, and observation changes NOTHING (served tokens
-  bit-identical with tracing on vs off).
+* SPANS — the serving plane's instrumentation yields a WELL-FORMED
+  interval forest that also lands in the ``jax.profiler`` trace, exports
+  as loadable Chrome-trace JSON, and observation changes NOTHING (served
+  tokens bit-identical with tracing on vs off). The device work is named
+  in the compiled HLO's ``op_name`` metadata instead: every collective
+  inside its channel's ``comm.flush.ch<i>`` scope, leader collectives
+  nested as ``comm.flush.ch<i>/comm.leader.l<j>``.
 * DETERMINISM — same seed + same ChaosPlan => byte-identical
   deterministic snapshot, across the hadronio-family modes x
   event_loops {1, 2, 4} and across every chaos scenario.
 * GATE — bench_diff tolerance-band units and CLI exit codes.
 """
+import glob
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +33,9 @@ from repro.core.backends import SyncContext, pipeline
 from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.obs import baseline as bl
-from repro.serving import chaos
-from repro.serving.dispatch import clear_serve_step_cache
+from repro.serving import chaos, make_engine_group
+from repro.serving.dispatch import clear_serve_step_cache, make_serve_step
+from repro.serving.event_loop import channel_affinity
 
 HADRONIO_FAMILY = ("hadronio", "hadronio_rs", "hadronio_overlap",
                    "hadronio_overlap_rs")
@@ -131,6 +136,8 @@ def test_recorder_nesting_and_round_trip():
     ok, problems = obs.well_formed(rec)
     assert ok, problems
     assert rec.kinds() == ["emission", "flush", "heal"]
+    assert [s.parent for s in rec.spans_of("flush")] == ["emission"] * 2
+    assert rec.spans_of("emission")[0].parent == ""
     # export -> json round-trip: loadable, complete events, us stamps
     doc = json.loads(json.dumps(rec.to_chrome()))
     evs = doc["traceEvents"]
@@ -210,37 +217,83 @@ def reference(tiny):
     return base, reqs
 
 
+_COLLECTIVE = re.compile(
+    r"= .*\b(?:all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start)?\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _op_names(text: str, collectives: bool = False) -> list:
+    """The ``op_name`` of every instruction (or every collective) of a
+    compiled HLO module's text."""
+    out = []
+    for line in text.splitlines():
+        m = _OP_NAME.search(line)
+        if m and (not collectives or _COLLECTIVE.search(line)):
+            out.append(m.group(1))
+    return out
+
+
+def _decode_hlo(cfg, serve, loop: int = 0, batch: int = 2,
+                max_len: int = 32) -> tuple:
+    """(compiled decode step text, owned channels) of one loop's engine."""
+    chans = channel_affinity(serve.comm.channels, serve.event_loops)[loop]
+    step = make_serve_step(cfg, serve.comm, channel_indices=chans)
+    sds = jax.ShapeDtypeStruct
+    lowered = step.decode.lower(
+        api.abstract(cfg), api.cache_specs(cfg, batch, max_len),
+        {"token": sds((batch,), jnp.int32), "pos": sds((batch,), jnp.int32)})
+    return lowered.compile().as_text(), chans
+
+
+def _flush_channels(names: list) -> set:
+    return {int(c) for n in names
+            for c in re.findall(r"comm\.flush\.ch(\d+)/", n)}
+
+
+ENGINE_KINDS = {"submit", "join", "drain", "wave_setup", "prefill",
+                "grow_cache", "decode", "host_step", "admission", "request"}
+
+
 def test_traced_serve_well_formed_and_token_identical(tiny, reference):
-    """One traced serve covers the whole span taxonomy: emission /
-    stage / flush from the staged emission API (trace-time), build from
-    the step builder, prefill / decode / admission from the engine,
-    drain from the event loop — well-formed, and OBSERVATION ONLY
-    (tokens bit-identical to the untraced run)."""
+    """One traced serve covers the engine's span taxonomy (submit / join
+    from the group, drain from the event loop, the wave's set-up, prefill
+    and cache growth, decode and host steps, admission, one request event
+    per request) — well-formed, and OBSERVATION ONLY (tokens bit-identical
+    to the untraced run). Every collective of the served step sits in a
+    channel flush scope of its compiled HLO."""
     cfg, params = tiny
     base, reqs = reference
     serve = chaos.chaos_serve_config("hadronio", 1)
 
     clear_serve_step_cache()
     off = chaos.run_baseline(cfg, params, serve, reqs)
-    clear_serve_step_cache()           # fresh trace => emission spans
+    clear_serve_step_cache()
     with obs.capture() as rec:
         on = chaos.run_baseline(cfg, params, serve, reqs)
     assert on.tokens == off.tokens == base.tokens
 
     kinds = set(rec.kinds())
-    assert {"emission", "stage", "flush", "build", "prefill", "decode",
-            "admission", "drain"} <= kinds, kinds
+    assert ENGINE_KINDS <= kinds, kinds
     ok, problems = obs.well_formed(rec)
     assert ok, problems
     assert rec.forced_closes == 0 and rec.open_spans() == []
-    # every flush nests inside an emission (the begin/finish bracket)
-    for f in rec.spans_of("flush"):
-        assert obs.containing(rec, f, "emission") is not None, f
+    assert {s.args["uid"] for s in rec.spans_of("request")} \
+        == {r.uid for r in reqs}
+    # every admission runs inside the host step of its flush boundary
+    for a in rec.spans_of("admission"):
+        assert obs.containing(rec, a, "host_step") is not None, a
+        assert a.parent == "host_step"
+    names = _op_names(_decode_hlo(cfg, serve)[0], collectives=True)
+    assert names and all(re.search(r"comm\.flush\.ch\d+/", n)
+                         for n in names), names
 
 
 @pytest.mark.parametrize("mode,el", [("hadronio", 1), ("hadronio", 2),
                                      ("hadronio_overlap", 2)])
 def test_tracing_preserves_tokens_per_mode(tiny, reference, mode, el):
+    """Tokens stay bit-identical with tracing on; each loop's decode step
+    flushes only on the channels that loop owns (its scopes say which)."""
     cfg, params = tiny
     base, reqs = reference
     serve = chaos.chaos_serve_config(mode, el)
@@ -248,13 +301,20 @@ def test_tracing_preserves_tokens_per_mode(tiny, reference, mode, el):
     with obs.capture() as rec:
         res = chaos.run_baseline(cfg, params, serve, reqs)
     assert res.tokens == base.tokens, (mode, el)
-    assert rec.spans_of("emission") and obs.well_formed(rec)[0]
+    assert rec.spans_of("decode") and obs.well_formed(rec)[0]
+    assert {s.args["loop"] for s in rec.spans_of("decode")} \
+        == set(range(el))
+    for loop in range(el):
+        text, chans = _decode_hlo(cfg, serve, loop)
+        used = _flush_channels(_op_names(text, collectives=True))
+        assert used and used <= set(chans), (loop, used, chans)
 
 
 def test_leader_flush_nests_inside_local_flush():
     """Two-level leader emission: the leader lane's cross-pod collective
     fires from INSIDE its triggering local lane's flush under
-    flush="ready" — the span tree must show that containment."""
+    flush="ready" — the compiled HLO's scopes must show that containment
+    (``comm.flush.ch<i>/comm.leader.l<j>``)."""
     mesh = make_mesh((1, 1), ("pod", "data"))
     comm = CommConfig(mode="hadronio", channels=4, aggregate="channel",
                       flush="ready", hierarchical=True, leader_channels=1,
@@ -271,19 +331,23 @@ def test_leader_flush_nests_inside_local_flush():
     x = jnp.arange(96, dtype=jnp.float32).reshape(1, 96)
     with obs.capture() as rec:
         np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x[0]))
-    leads = rec.spans_of("leader_flush")
-    assert leads, "hierarchical emission must record leader flushes"
-    for l in leads:
-        host = obs.containing(rec, l, "flush")
-        assert host is not None, (l.name, "no containing local flush")
-    ok, problems = obs.well_formed(rec)
-    assert ok, problems
+    assert rec.spans_of("flush") == [] and obs.well_formed(rec)[0]
+    names = _op_names(f.lower(x).compile().as_text(), collectives=True)
+    leads = [n for n in names if "comm.leader.l" in n]
+    assert leads, "hierarchical emission must scope leader flushes"
+    for n in leads:
+        assert re.search(r"comm\.flush\.ch\d+/comm\.leader\.l\d+/", n), \
+            (n, "no containing local flush")
+    # the in-pod stages are local flushes of their own
+    assert any(re.search(r"comm\.flush\.ch\d+/[^/]*$", n) for n in names)
 
 
 def test_supervised_heal_spans_complete_taxonomy(tiny, reference):
     """The acceptance trace: a supervised dropped_flush run records >= 4
-    span kinds including emission, flush, admission and heal — and the
-    healing spans carry the supervisor's detect->heal window."""
+    span kinds including decode, host_step, admission and heal, and the
+    healing spans carry the supervisor's detect->heal window. A dropped
+    flush is re-flushed at the step barrier inside its own channel's
+    scope, so the faulted program's collectives are all scoped."""
     cfg, params = tiny
     base, reqs = reference
     serve = chaos.chaos_serve_config("hadronio", 1)
@@ -292,13 +356,124 @@ def test_supervised_heal_spans_complete_taxonomy(tiny, reference):
                                    reqs, seed=11, baseline=base)
     assert res.report.recovered and res.tokens == base.tokens
     kinds = set(rec.kinds())
-    assert {"emission", "flush", "admission", "heal"} <= kinds, kinds
+    assert {"decode", "host_step", "admission", "heal"} <= kinds, kinds
     assert len(kinds) >= 4
     heals = rec.spans_of("heal")
     assert {s.name for s in heals} >= {"quarantine", "restart"}
     assert all(s.dur >= 0 for s in heals)
     ok, problems = obs.well_formed(rec)
     assert ok, problems
+    pipeline.set_flush_fault(lambda c: "drop")
+    try:
+        text, chans = _decode_hlo(cfg, serve)
+    finally:
+        pipeline.clear_flush_fault()
+    names = _op_names(text, collectives=True)
+    assert names and _flush_channels(names) <= set(chans)
+    assert all(re.search(r"comm\.flush\.ch\d+/", n) for n in names)
+
+
+def test_profiler_trace_holds_engine_spans(tiny, reference, tmp_path):
+    """Under ``jax.profiler`` every span is a ``repro.<kind>`` host event
+    whose stats are its args: the decode steps carry ``active``, each
+    finished request its timeline."""
+    cfg, params = tiny
+    base, reqs = reference
+    serve = chaos.chaos_serve_config("hadronio", 1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.capture():
+            chaos.run_baseline(cfg, params, serve, reqs)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    events.setdefault(ev.name, []).append(dict(ev.stats))
+    dec = events["repro.decode"]
+    assert dec and all(0 < st["active"] <= serve.max_batch for st in dec)
+    req = events["repro.request"]
+    assert {st["uid"] for st in req} == {r.uid for r in reqs}
+    assert all(0 <= st["ttft_ms"] <= st["done_ms"] for st in req)
+
+
+def test_decode_spans_close_after_their_token_is_read(tiny, reference):
+    """A decode span runs from the step's enqueue until its sampled
+    tokens are ready on the host: it holds the ready time of the tokens
+    it produced, the host step that reads them starts as it ends, and
+    there is one per loop step (a wave's last step decodes nothing)."""
+    cfg, params = tiny
+    _, reqs = reference
+    serve = chaos.chaos_serve_config("hadronio", 1)
+    grp = make_engine_group(cfg, params, serve)
+    with obs.capture() as rec:
+        grp.submit(list(reqs))
+        results = grp.run(threads=False)
+    ready = sorted({float(t) for r in results for t in r.token_times})
+    dec, host = rec.spans_of("decode"), rec.spans_of("host_step")
+    waves = len(rec.spans_of("prefill"))
+    assert dec and len(host) == len(dec) + waves
+    by_step = {(s.args["loop"], s.args["step"]): s for s in host}
+    for d in dec:
+        h = by_step[(d.args["loop"], d.args["step"])]
+        assert d.t1 <= h.t0 + 1e-9 and h.t0 - d.t1 < 0.05, (d, h)
+        lo, hi = d.t0 + rec._epoch, d.t1 + rec._epoch
+        assert any(lo <= t <= hi for t in ready), d
+
+
+def test_result_timeline_is_ordered(tiny, reference):
+    """Every Result carries its timeline: submitted, then its first
+    token, then each token in order, then done; one time per token,
+    through the continuous-admission path too."""
+    cfg, params = tiny
+    _, reqs = reference
+    serve = chaos.chaos_serve_config("hadronio", 2)
+    grp = make_engine_group(cfg, params, serve)
+    grp.submit(list(reqs))
+    results = grp.run(threads=True)
+    assert {r.uid for r in results} == {r.uid for r in reqs}
+    for r in results:
+        t = np.asarray(r.token_times)
+        assert len(t) == len(r.tokens) > 0
+        assert r.t_submit <= r.t_first == t[0]
+        assert np.all(np.diff(t) >= 0) and t[-1] <= r.t_done
+
+
+def _lowered_scopes(text: str) -> set:
+    return {p for n in _op_names(text) for p in
+            re.split(r"[/()]", n.split(";")[0])}
+
+
+def test_model_scopes_name_the_device_work(tiny):
+    """``attn``, ``mlp`` and ``lm_head`` (and the embedding, the layer
+    loop and the optimizer) name the compiled train and decode steps'
+    ops, forward and backward."""
+    from repro.configs.base import RunConfig, ShapeConfig
+    from repro.configs.registry import get_config
+    from repro.launch import steps as steps_mod
+    cfg = get_config("qwen2-0.5b-reduced")
+    run = RunConfig(model=cfg, shape=ShapeConfig("t", "train", 16, 4),
+                    comm=CommConfig(mode="hadronio_overlap",
+                                    hierarchical=False))
+    mesh = make_mesh((1,), ("data",))
+    with jax.set_mesh(mesh):
+        step_fn, _, _ = steps_mod.make_train_step(run, mesh)
+        state = steps_mod.init_tac_state(jax.random.PRNGKey(0), run, 1)
+        batch = {"tokens": jnp.zeros((4, 16), jnp.int32),
+                 "labels": jnp.zeros((4, 16), jnp.int32)}
+        train = jax.jit(step_fn).lower(state, batch).compile().as_text()
+    got = _lowered_scopes(train)
+    assert {"embed", "attn", "mlp", "lm_head", "layers",
+            "optimizer"} <= got, got
+    assert any(n.startswith("transpose(jvp(") or "/transpose(jvp(" in n
+               for n in _op_names(train))
+    tcfg, _ = tiny
+    decode, _ = _decode_hlo(tcfg, chaos.chaos_serve_config("hadronio", 1))
+    assert {"embed", "attn", "mlp", "lm_head", "layers"} \
+        <= _lowered_scopes(decode)
 
 
 # ---------------------------------------------------------------------------
