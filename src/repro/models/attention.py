@@ -1,12 +1,15 @@
 """Attention: GQA projections + chunked online-softmax attention.
 
-Three execution regimes:
+Four execution regimes:
 
 * ``attend_chunked`` — train/prefill. Outer python loop over query chunks
   (static per-chunk KV prefix => causal FLOPs ~= S^2/2, not S^2), inner
   ``lax.scan`` over KV chunks with online softmax (flash-style; bounded
   VMEM/HBM working set). Sliding windows slice a static band per q-chunk.
-* ``attend_direct`` — short sequences (encoders) and decode (Sq == 1).
+* ``attend_direct`` — short sequences (one-chunk prefill, cross-attention).
+* ``decode_attend`` — one token against the KV cache. Each kv head's
+  query group is contracted against the cache as stored, (B, S, KV, Dh);
+  the cache is never expanded to the query heads.
 * ``kernels/flash_attention.py`` — the Pallas TPU production kernel; this
   module is its jnp oracle and the CPU/dry-run path.
 
@@ -75,7 +78,11 @@ def out_project(p: dict, attn: jax.Array, shard_fn: ShardFn = no_shard):
 
 def expand_kv(k: jax.Array, num_heads: int) -> jax.Array:
     """(B,S,KV,Dh) -> (B,S,H,Dh) by broadcasting each kv head over its
-    query group (XLA fuses the broadcast into the downstream dot)."""
+    query group (query head h reads kv head h // (H // KV)); used by the
+    train, prefill and cross-attention paths. XLA need not fuse this
+    broadcast into the dot that reads it: on a TPU it wrote the decode
+    cache out as f32 at H heads, so ``decode_attend`` groups the query
+    heads instead of calling this."""
     b, s, kv, dh = k.shape
     g = num_heads // kv
     if g == 1:
@@ -274,13 +281,18 @@ def decode_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     batches). Full attention writes slot ``pos``; windowed caches are
     rolling (slot = pos % window, S_max == window).
 
+    Grouped heads: q is reshaped to (B, KV, G, Dh), G = H // KV, and each
+    group is contracted in the cache dtype (f32 accumulation) against its
+    kv head of the (B, S_max, KV, Dh) cache, so no H-headed copy of the
+    cache is made. G == 1
+    (MHA) is the same contraction.
+
     Sharding (§Perf B2, flash-decoding layout): when kv-heads don't
     divide the model axis, the cache shards its LENGTH dim over
     ``model``; q is pinned replicated (tiny), scores stay length-sharded
     (softmax max/sum become small psums), and the output is resharded to
     heads late — so no cache-sized gather ever materializes."""
     s_max = cache_k.shape[1]
-    q = shard_fn(q, ("batch", "rep", "rep", "rep"))
     cache_k = shard_fn(cache_k, ("batch", "seq_model", "rep", "rep"))
     cache_v = shard_fn(cache_v, ("batch", "seq_model", "rep", "rep"))
     slot = pos % s_max if window > 0 else pos
@@ -294,13 +306,12 @@ def decode_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         cache_k = cache_k.at[b_idx, slot].set(new_k[:, 0])
         cache_v = cache_v.at[b_idx, slot].set(new_v[:, 0])
 
-    kx = expand_kv(cache_k, num_heads)
-    vx = expand_kv(cache_v, num_heads)
-    kx = shard_fn(kx, ("batch", "seq_model", "rep", "rep"))
-    vx = shard_fn(vx, ("batch", "seq_model", "rep", "rep"))
-    dh = q.shape[-1]
+    b, _, kv, dh = cache_k.shape
+    g = num_heads // kv
+    # query head h reads kv head h // g, the grouping expand_kv uses
+    qg = shard_fn(q.reshape(b, kv, g, dh), ("batch", "rep", "rep", "rep"))
     scale = 1.0 / math.sqrt(dh)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kx,
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, cache_k,
                    preferred_element_type=jnp.float32) * scale
     s = shard_fn(s, ("batch", "rep", "rep", "seq_model"))
     j = jnp.arange(s_max)
@@ -313,7 +324,8 @@ def decode_attend(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
         else valid[:, None, None, :]
     s = jnp.where(valid, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vx.dtype), vx,
+    out = jnp.einsum("bkgs,bskd->bkgd", p.astype(cache_v.dtype), cache_v,
                      preferred_element_type=jnp.float32).astype(q.dtype)
+    out = out.reshape(b, 1, num_heads, dh)
     out = shard_fn(out, ("batch", None, "heads", None))   # late reshard
     return out, cache_k, cache_v
