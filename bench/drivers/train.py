@@ -3,7 +3,10 @@
 
 Set-up builds one trainer, hands it the benchmark's weights and drives it
 through its first ``checked_steps`` steps with the window's own call and
-feed. The window then continues the same state. After the window the
+feed. The window then continues the same state, dispatching about
+``AHEAD_S`` seconds of steps ahead of the loss it waits for, so that the
+chip keeps working while the host stands still; when its time is up it
+sends nothing more and waits for every step sent. After the window the
 plain reference repeats the checked steps from the same weights on the same
 rows, and three numbers are compared:
 
@@ -20,7 +23,9 @@ rows, and three numbers are compared:
 """
 from __future__ import annotations
 
+import collections
 import gc
+import math
 import time
 
 import jax
@@ -31,6 +36,7 @@ from jax.sharding import AxisType
 from bench import flops, harness, model, reference, traffic
 
 MIN_LEAF_GRAD = 1e-3          # share of the median leaf's gradient norm
+AHEAD_S = 4.0                 # seconds of steps dispatched ahead in the window
 
 
 class Session:
@@ -104,19 +110,24 @@ def _named(tree) -> dict:
 
 def first_steps(sess: Session, seed: int, state) -> tuple:
     """The checked steps through the window's call and feed: (state after
-    them, program readings). Step 1 compiles."""
+    them, program readings, and ``step_s``, the quickest of the steps
+    after the first, which compiles)."""
     n = sess.mix["checked_steps"]
     b1 = sess.mix["optimizer"]["beta1"]
-    losses, grad = [], None
+    losses, grad, step_s = [], None, []
     for k in range(1, n + 1):
+        t = time.perf_counter()
         state, m = sess.step(state, sess.batch(seed, k))
         losses.append(float(m["loss"]))
         if k == 1:
             grad = {p: v / (1.0 - b1)
                     for p, v in _named(_leaf_norms(state.opt.mu)).items()}
+        else:
+            step_s.append(time.perf_counter() - t)
     delta = _named(_delta_norms(state.params,
                                 model.init_weights(sess.cfg, seed)))
-    return state, {"losses": losses, "grad": grad, "delta": delta}
+    return state, {"losses": losses, "grad": grad, "delta": delta,
+                   "step_s": min(step_s, default=None)}
 
 
 def reference_readings(cfg: dict, mix: dict, seed: int, rows: int,
@@ -166,6 +177,13 @@ def compare(prog: dict, ref: dict) -> dict:
     }
 
 
+def steps_ahead(step_s) -> int:
+    """Steps that hold the chip for about ``AHEAD_S`` seconds."""
+    if not step_s:
+        return 1
+    return max(1, math.ceil(AHEAD_S / step_s))
+
+
 def run(ctx) -> dict:
     sess = Session(ctx.mc, ctx.cfg, ctx.mix, ctx.devs)
     if ctx.fault:
@@ -176,21 +194,33 @@ def run(ctx) -> dict:
     nxt = sess.batch(ctx.seed, step)
     setup_s = time.perf_counter() - ctx.t_start
 
+    ahead = steps_ahead(prog["step_s"])
     tracer = harness.Tracer(ctx.trace)
-    times, losses = [], []
-    with tracer.window():
-        t0 = last = time.perf_counter()
-        while True:
-            with jax.profiler.TraceAnnotation("bench.train_step"):
-                state, m = sess.step(state, nxt)
-                step += 1
-                nxt = sess.batch(ctx.seed, step)
-                losses.append(float(m["loss"]))
-            now = time.perf_counter()
-            times.append(now - last)
-            last = now
-            if now - t0 >= ctx.window_seconds():
-                break
+    times, losses, pending = [], [], collections.deque()
+
+    def read_oldest(last):
+        losses.append(float(pending.popleft()))
+        now = time.perf_counter()
+        times.append(now - last)
+        return now
+
+    harness.settle()
+    try:
+        with tracer.window():
+            t0 = last = time.perf_counter()
+            while time.perf_counter() - t0 < ctx.window_seconds():
+                with jax.profiler.TraceAnnotation("bench.train_step"):
+                    state, m = sess.step(state, nxt)
+                    step += 1
+                    nxt = sess.batch(ctx.seed, step)
+                    pending.append(m["loss"])
+                    if len(pending) > ahead:
+                        last = read_oldest(last)
+            with jax.profiler.TraceAnnotation("bench.drain"):
+                while pending:
+                    last = read_oldest(last)
+    finally:
+        harness.unsettle()
     window_s = last - t0
     device = harness.device_info(ctx.devs)
     del state, nxt, m, sess

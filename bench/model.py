@@ -28,9 +28,18 @@ def dtype_of(cfg: dict):
     return jnp.dtype(cfg["torch_dtype"])
 
 
+def sliding_window(cfg: dict) -> int:
+    """The window the file states (a query at q sees keys k with
+    0 <= q - k < window), or 0 for full causal attention."""
+    if not cfg.get("use_sliding_window", True):
+        return 0
+    return cfg.get("sliding_window") or 0
+
+
 def program_config(cfg: dict):
     """The repo's ModelConfig for ``cfg["arch"]``, checked against every
-    width, the block's kind and the dtype the file states."""
+    width, the block's kind, the window, tying, RoPE base and the dtype
+    the file states."""
     from repro.configs.registry import get_config
     mc = get_config(cfg["arch"])
     want = {field: cfg[key] for key, field in WIDTHS.items()}
@@ -42,8 +51,7 @@ def program_config(cfg: dict):
         else "layernorm",
         tie_embeddings=cfg["tie_word_embeddings"],
         rope_theta=cfg["rope_theta"],
-        sliding_window=cfg.get("sliding_window") or 0
-        if cfg.get("use_sliding_window", True) else 0,
+        sliding_window=sliding_window(cfg),
         param_dtype=cfg["torch_dtype"],
         compute_dtype=cfg["torch_dtype"])
     got = {k: getattr(mc, k) for k in want}

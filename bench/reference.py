@@ -2,12 +2,15 @@
 
 Straight ``jax.numpy`` from the published block description: pre-norm
 (RMSNorm, or LayerNorm with a shift), grouped-query attention with RoPE
-(rotate-half) and optional q/k/v biases, a gated SiLU MLP or a plain
-tanh-GELU MLP with biases, a final norm and an LM head that is the
-embedding transposed when tied. No cache, no batching tricks, no kernels,
-and nothing imported from the system under test. Weights are kept in the
-dtype the configuration stores them in and widened to float32 layer by
-layer; every matmul runs at ``highest`` precision.
+(rotate-half), optional q/k/v biases and an optional sliding window, a
+gated SiLU MLP or a plain tanh-GELU MLP with biases, a final norm and an
+LM head that is the embedding transposed when tied. Attention takes a
+block of queries at a time against the keys they can see, so that long
+sequences fit; every configuration takes that one path. No cache, no
+batching tricks, no kernels, and nothing imported from the system under
+test. Weights are kept in the dtype the configuration stores them in and
+widened to float32 layer by layer; every matmul runs at ``highest``
+precision.
 
 ``prec="fp8"`` is the control: each matmul's operands, and in training
 their cotangents, are rounded to float8 e4m3 with a per-tensor scale, the
@@ -22,8 +25,11 @@ import math
 import jax
 import jax.numpy as jnp
 
+from bench.model import sliding_window
+
 F32 = jnp.float32
 FP8_MAX = 448.0                       # largest finite float8 e4m3fn
+QUERY_BLOCK = 512                     # queries per block of attention
 
 
 def _round8(x):
@@ -73,6 +79,29 @@ def _gelu_tanh(x):
                                      * (x + 0.044715 * x ** 3)))
 
 
+def attend(q, k, v, window: int, prec: str):
+    """Causal softmax attention; q, k, v: (B, S, heads, dh), one kv head
+    per query head. With ``window`` > 0 a query at q sees the keys at k
+    with 0 <= q - k < window. Computed ``QUERY_BLOCK`` queries at a time
+    against the keys that block can see, so that no S x S score matrix is
+    made."""
+    s, dh = q.shape[1], q.shape[-1]
+    outs = []
+    for lo in range(0, s, QUERY_BLOCK):
+        hi = min(lo + QUERY_BLOCK, s)
+        k_lo = max(0, lo - window + 1) if window else 0
+        qp = jnp.arange(lo, hi)[:, None]
+        kp = jnp.arange(k_lo, hi)[None, :]
+        seen = kp <= qp
+        if window:
+            seen &= qp - kp < window
+        scores = _mm("bqhd,bkhd->bhqk", q[:, lo:hi], k[:, k_lo:hi],
+                     prec) / math.sqrt(dh)
+        probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+        outs.append(_mm("bhqk,bkhd->bqhd", probs, v[:, k_lo:hi], prec))
+    return jnp.concatenate(outs, axis=1)
+
+
 def _layer(p, x, cfg, prec):
     """One decoder block; x: (B, S, d) float32."""
     h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
@@ -86,12 +115,7 @@ def _layer(p, x, cfg, prec):
     q, k = _rope(q, cfg["rope_theta"]), _rope(k, cfg["rope_theta"])
     k = jnp.repeat(k, h // kv, axis=2)          # head i reads kv head i//g
     v = jnp.repeat(v, h // kv, axis=2)
-    s = x.shape[1]
-    scores = _mm("bqhd,bkhd->bhqk", q, k, prec) / math.sqrt(q.shape[-1])
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    att = _mm("bhqk,bkhd->bqhd", probs, v, prec)
+    att = attend(q, k, v, sliding_window(cfg), prec)
     x = x + _mm("bshk,hkd->bsd", att, a["wo"], prec)
     m = p["mlp"]
     y = _norm(p["ln2"], x, cfg)

@@ -8,7 +8,7 @@ import time
 
 import jax
 
-from bench import harness, peaks
+from bench import harness, model, peaks
 from bench.run import Context
 
 BENCH = harness.benchmark()
@@ -31,7 +31,8 @@ def shrink(cfg: dict, **sizes) -> dict:
 
 
 def small_program_config(cfg: dict):
-    """The repo's ModelConfig at ``cfg``'s (test-sized) widths."""
+    """The repo's ModelConfig at ``cfg``'s (test-sized) widths, with the
+    window, tying and RoPE base the file states."""
     from repro.configs.registry import get_config
     return dataclasses.replace(
         get_config(cfg["arch"]), num_layers=cfg["num_hidden_layers"],
@@ -39,6 +40,9 @@ def small_program_config(cfg: dict):
         num_kv_heads=cfg["num_key_value_heads"],
         d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
         head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
+        sliding_window=model.sliding_window(cfg),
+        tie_embeddings=cfg["tie_word_embeddings"],
+        rope_theta=cfg["rope_theta"],
         param_dtype=cfg["torch_dtype"], compute_dtype=cfg["torch_dtype"])
 
 

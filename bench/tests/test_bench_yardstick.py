@@ -47,6 +47,39 @@ def test_starcoder2_flops_by_hand():
                                                                 rel=1e-3)
 
 
+def test_starcoder2_decode_counts_the_window():
+    # the published window; the repo's starcoder2-3b runs full attention
+    cfg = dict(cfg_named("starcoder2-3b"), sliding_window=4096)
+    base = 2 * (flops.layer_matmul_params(cfg) + flops.head_params(cfg))
+    per_key = 4 * 24 * 128 * 30
+    assert flops.decode_flops(cfg, 4094) == pytest.approx(base
+                                                          + per_key * 4095)
+    for pos in (4095, 4096, 8703, 16383):
+        assert flops.keys_seen(cfg, pos) == 4096
+        assert flops.decode_flops(cfg, pos) == pytest.approx(
+            base + per_key * 4096)
+
+
+@pytest.mark.parametrize("n", [1, 5, 16, 17, 40, 512])
+def test_prompt_and_train_keys_count_the_window(n):
+    cfg = dict(cfg_named("starcoder2-3b"), sliding_window=16)
+    keys = sum(min(i + 1, 16) for i in range(n))
+    assert flops.prompt_keys(cfg, n) == keys
+    base = 2 * (flops.layer_matmul_params(cfg) + flops.head_params(cfg))
+    assert flops.train_flops_per_token(cfg, n) == pytest.approx(
+        3 * (base + flops.attention_flops(cfg, keys / n)))
+    assert flops.prefill_flops(cfg, n) == pytest.approx(
+        2 * flops.layer_matmul_params(cfg) * n
+        + 2 * flops.head_params(cfg) + flops.attention_flops(cfg, keys))
+
+
+def test_qwen2_counts_every_key():
+    # qwen2 states a window of 131,072 and use_sliding_window false
+    cfg = cfg_named("qwen2-0.5b")
+    assert flops.keys_seen(cfg, 200_000) == 200_001
+    assert flops.prompt_keys(cfg, 200_000) == 200_000 * 200_001 / 2
+
+
 def test_tied_head_is_counted():
     cfg = cfg_named("qwen2-0.5b")
     assert cfg["tie_word_embeddings"]
@@ -183,6 +216,28 @@ def test_weight_tree_is_the_programs(name):
                        model.weight_shapes(cfg),
                        is_leaf=lambda x: isinstance(x, tuple))
     assert got == want
+
+
+def test_qwen2_runs_as_the_registry_has_it():
+    from repro.configs.registry import get_config
+    assert model.program_config(cfg_named("qwen2-0.5b")) == get_config(
+        "qwen2-0.5b")
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("change", [
+    lambda c: dict(c, sliding_window=4096, use_sliding_window=True),
+    lambda c: dict(c, tie_word_embeddings=not c["tie_word_embeddings"]),
+    lambda c: dict(c, rope_theta=999999.4420358813),
+    lambda c: dict(c, torch_dtype="float32"),
+], ids=["window", "tying", "rope_theta", "dtype"])
+def test_program_config_refuses_what_the_registry_runs_otherwise(
+        name, change):
+    """A file the registry's entry departs from is refused, not run as
+    some other model (starcoder2-3b's published window, tying, RoPE base
+    and dtype among them)."""
+    with pytest.raises(ValueError, match="differs from the configuration"):
+        model.program_config(change(cfg_named(name)))
 
 
 @pytest.mark.parametrize("name", CONFIGS)
